@@ -2,12 +2,16 @@
 ``native/`` (used by :mod:`.wgl_native` and :mod:`.preproc_native`).
 
 Each helper is one translation unit compiled with g++ into
-``jepsen_tpu/_build/lib*.so`` the first time it is needed; callers fall
-back to their pure-Python paths when the toolchain is unavailable.
+``jepsen_tpu/_build/lib*-<key>.so`` the first time it is needed, where
+``<key>`` hashes the source and the compiler flags: a library built
+from another revision of the source (a copied ``_build/`` directory,
+whatever its mtimes) is never loaded. Callers fall back to their
+pure-Python paths when the toolchain is unavailable.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,6 +21,7 @@ _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native")
 _BUILD_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "_build")
+_FLAGS = ("-O2", "-std=c++17", "-shared", "-fPIC")
 
 
 class NativeLib:
@@ -30,24 +35,31 @@ class NativeLib:
     def __init__(self, src_name: str, so_name: str,
                  declare: Callable[[ctypes.CDLL], None]) -> None:
         self._src = os.path.join(_NATIVE_DIR, src_name)
-        self._so = os.path.join(_BUILD_DIR, so_name)
+        self._so_name = so_name
         self._declare = declare
         self._lock = threading.Lock()
         self._lib: Optional[ctypes.CDLL] = None
         self.error: Optional[str] = None
 
+    def _so_path(self) -> str:
+        """The library's path, keyed by a hash of its source and flags."""
+        with open(self._src, "rb") as f:
+            key = hashlib.sha256(f.read() + " ".join(_FLAGS).encode())
+        stem, ext = os.path.splitext(self._so_name)
+        return os.path.join(_BUILD_DIR,
+                            f"{stem}-{key.hexdigest()[:16]}{ext}")
+
     def _build(self) -> Optional[str]:
         try:
-            if (os.path.exists(self._so) and
-                    os.path.getmtime(self._so) >= os.path.getmtime(self._src)):
+            self._so = self._so_path()
+            if os.path.exists(self._so):
                 return None
             os.makedirs(_BUILD_DIR, exist_ok=True)
             # per-process tmp name: concurrent builders each write their
             # own file and the os.replace install stays atomic
             tmp = f"{self._so}.{os.getpid()}.tmp"
             p = subprocess.run(
-                ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
-                 "-o", tmp, self._src],
+                ["g++", *_FLAGS, "-o", tmp, self._src],
                 capture_output=True, text=True, timeout=120)
             if p.returncode != 0:
                 return f"g++ failed: {p.stderr[:500]}"
@@ -75,3 +87,8 @@ class NativeLib:
 
     def available(self) -> bool:
         return self.load() is not None
+
+    def build_error(self) -> Optional[str]:
+        """Why the library is unavailable (None once it loaded)."""
+        self.load()
+        return self.error

@@ -81,6 +81,7 @@ def _declare(lib: ctypes.CDLL) -> None:
 
 _NATIVE = NativeLib("preproc.cpp", "libjepsen_preproc.so", _declare)
 _load = _NATIVE.load
+build_error = _NATIVE.build_error
 
 
 def available() -> bool:

@@ -44,6 +44,7 @@ behavior being reproduced: knossos.wgl per-history semantics
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 from typing import List, Optional, Sequence, Tuple
@@ -734,10 +735,7 @@ def _pipe_walk_on(device, host_args, geom, n_pass: int, interpret: bool,
     per-shard body of the mesh lockstep lane — jax routes the jitted
     walk to wherever its operands are committed, so N shards queued on
     N devices walk concurrently."""
-    if device is None:
-        return _pipe_walk_b(host_args, geom, n_pass, interpret, dsegs)
-    import jax
-    with jax.default_device(device):
+    with _on_device(device):
         return _pipe_walk_b(host_args, geom, n_pass, interpret, dsegs,
                             device=device)
 
@@ -764,7 +762,6 @@ def _dispatch_words(prep: BatchPrepared) -> BatchInflight:
     one shared transition table derived from P, per-lane word-vector
     frontiers, the whole group as ONE vmapped scan — nothing fetched
     (the queued device results ride ``word_out`` into the collect)."""
-    import jax
     import jax.numpy as jnp
 
     from jepsen_tpu.checkers import reach_word
@@ -784,16 +781,10 @@ def _dispatch_words(prep: BatchPrepared) -> BatchInflight:
         int(Tpad.nbytes + H * S * M * 4
             + (rs_hr.size + so_hrw.size) * 4))
 
-    def _go():
-        return reach_word._jitted_walk_words_batch()(
+    with _on_device(prep.device):
+        out = reach_word._jitted_walk_words_batch()(
             jnp.asarray(Tpad), jnp.asarray(R0w), jnp.asarray(rs_hr),
             jnp.asarray(so_hrw))
-
-    if prep.device is not None:
-        with jax.default_device(prep.device):
-            out = _go()
-    else:
-        out = _go()
     obs.count("lockstep.word_groups")
     fl = BatchInflight(prep.P, prep.geom, prep.host_args, prep.R_lens,
                        {}, [], None, prep.interpret,
@@ -943,13 +934,23 @@ def collect_returns_batch(fl: BatchInflight) -> np.ndarray:
         occ = col.reshape(n_blocks, -1).any(axis=1)
         first_empty = int(np.argmin(occ)) if not occ.all() else n_blocks
         blk = max(0, first_empty - 1)
-        dead[h] = _refine_dead(
-            P, W, M,
-            np.ascontiguousarray(rs_rh[:, h].astype(np.int32)),
-            np.ascontiguousarray(ops_rhw[:, h, :]),
-            col[blk].T > 0.5, blk * B,
-            min(B, max(1, R_lens[h] - blk * B)))
+        # a mesh group re-walks on ITS chip, not the default device
+        with _on_device(fl.device):
+            dead[h] = _refine_dead(
+                P, W, M,
+                np.ascontiguousarray(rs_rh[:, h].astype(np.int32)),
+                np.ascontiguousarray(ops_rhw[:, h, :]),
+                col[blk].T > 0.5, blk * B,
+                min(B, max(1, R_lens[h] - blk * B)))
     return dead
+
+
+def _on_device(device):
+    """``jax.default_device(device)``, or no-op for None."""
+    if device is None:
+        return contextlib.nullcontext()
+    import jax
+    return jax.default_device(device)
 
 
 def walk_returns_batch(P: np.ndarray, ret_slots: List[np.ndarray],

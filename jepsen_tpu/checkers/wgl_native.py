@@ -44,9 +44,7 @@ def available() -> bool:
     return _NATIVE.available()
 
 
-def build_error() -> Optional[str]:
-    load()
-    return _NATIVE.error
+build_error = _NATIVE.build_error
 
 
 class AbortFlag:

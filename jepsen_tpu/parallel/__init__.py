@@ -38,21 +38,13 @@ def mesh(axis: str = "shard", devs: Optional[Sequence] = None):
 
 def shard_map(fn, mesh, in_specs, out_specs,
               check: Optional[bool] = None):
-    """``jax.shard_map`` across jax versions: the top-level API with
-    ``check_vma`` (jax >= 0.6) or the 0.4 experimental module with its
-    ``check_rep`` spelling — one call site for every sharded engine so
-    a jax upgrade touches only this shim. ``check=None`` keeps the
-    library default; False skips the replication/varying-axes check."""
+    """``jax.shard_map`` — one call site for every sharded engine.
+    ``check=None`` keeps the library default; False skips the
+    varying-axes check (``check_vma``)."""
     import jax
-    kw = {} if check is None else (
-        {"check_vma": check} if hasattr(jax, "shard_map")
-        else {"check_rep": check})
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
+    kw = {} if check is None else {"check_vma": check}
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def device_order(devs: Optional[Sequence] = None,

@@ -165,11 +165,7 @@ def initialize(coordinator_address: Optional[str] = None,
         # CPU fleets (tests, the dist-smoke CI job) need an explicit
         # collectives backend; gloo ships with jaxlib. Must be set
         # before the first backend spins up.
-        try:
-            jax.config.update(
-                "jax_cpu_collectives_implementation", "gloo")
-        except Exception:                           # noqa: BLE001
-            pass                    # older jaxlib: single-process only
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     try:
         jax.distributed.initialize(
             coordinator_address=coordinator_address,

@@ -1014,6 +1014,11 @@ class Daemon:
         dangles if the winner's admission then fails."""
         deadline = time.monotonic() + 5.0
         while True:
+            # read the in-flight mark BEFORE the tiers: a winner that
+            # settles between a tier miss and a later mark check would
+            # otherwise look failed, and this duplicate admit fresh
+            with self._idem_lock:
+                in_flight = known in self._admitting
             req = self.registry.get(known)
             if req is not None:
                 obs.count("serve.journal.deduped")
@@ -1037,15 +1042,15 @@ class Daemon:
                              "deduped": True, "fleet": True,
                              "claimed-by":
                                  self.journal.lease_live(known)}
-            with self._idem_lock:
-                if known not in self._admitting:
-                    # not mid-admission and resolvable on no tier:
-                    # either the winner's admission failed (its
-                    # retraction already popped the index) or the
-                    # entry fell out of retention — admit fresh
+            if not in_flight:
+                # not mid-admission and resolvable on no tier:
+                # either the winner's admission failed (its
+                # retraction already popped the index) or the
+                # entry fell out of retention — admit fresh
+                with self._idem_lock:
                     if self._idem.get((tenant, idem)) == known:
                         self._idem.pop((tenant, idem), None)
-                    return None
+                return None
             if time.monotonic() >= deadline:
                 # pathological stall of the winner: fail THIS
                 # duplicate loudly rather than dangle or double-admit

@@ -31,24 +31,33 @@ _LIVE_KEYS = ("client", "db", "os", "net", "nemesis", "generator", "checker",
 # -- persistent warm-start caches ------------------------------------------
 #
 # Fresh processes re-paid XLA compilation for every kernel geometry and
-# re-ran the memo BFS for every alphabet (ISSUE 3): the persistent tier
-# lives under the store dir — ``<store-root>/.cache/{xla,memo}`` — so a
-# recheck of a stored run starts warm. ``JEPSEN_TPU_NO_PERSIST=1``
-# disables everything; ``JEPSEN_TPU_CACHE_DIR`` relocates it.
+# re-ran the memo BFS for every alphabet (ISSUE 3). Two tiers:
+#
+# - XLA: jax's persistent compilation cache. Where the environment sets
+#   ``JAX_COMPILATION_CACHE_DIR`` that setting stands and nothing here
+#   picks a directory; otherwise one fixed directory inside the
+#   checkout (:data:`XLA_CACHE_DIR`), whatever the CWD or store root —
+#   the path is part of the cache key, so a moving directory never hits.
+# - memo/autotune: ``<store-root>/.cache`` (``JEPSEN_TPU_CACHE_DIR``
+#   relocates it).
+#
+# ``JEPSEN_TPU_NO_PERSIST=1`` disables both.
 
 _PERSIST_STATE: Dict[str, Any] = {}
 
+XLA_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".cache", "xla")
+
 
 def persist_root(store_root: Optional[str] = None) -> Optional[str]:
-    """Root directory of the persistent caches, or None when
+    """Root directory of the memo and autotune caches, or None when
     persistence is disabled (``JEPSEN_TPU_NO_PERSIST=1``). Defaults to
     ``<store-root>/.cache`` — keyed under the store dir so the caches
     travel with the runs they warmed — overridable via
     ``JEPSEN_TPU_CACHE_DIR``. With no explicit ``store_root``, the
-    last root wired through :func:`enable_compilation_cache` applies
-    (a run configured with a custom ``store-root`` re-keys BOTH tiers
-    — XLA and memo — away from the CWD default). Env is consulted per
-    call (tests toggle it at runtime)."""
+    last run dir's root (:func:`create_run_dir`) applies. Env is
+    consulted per call (tests toggle it at runtime)."""
     if os.environ.get("JEPSEN_TPU_NO_PERSIST"):
         return None
     d = os.environ.get("JEPSEN_TPU_CACHE_DIR")
@@ -58,43 +67,33 @@ def persist_root(store_root: Optional[str] = None) -> Optional[str]:
     return os.path.join(root, ".cache")
 
 
-def enable_compilation_cache(store_root: Optional[str] = None
-                             ) -> Optional[str]:
-    """Point jax's persistent compilation cache at
-    ``<persist-root>/xla`` so rechecks and fresh processes skip XLA
-    recompiles of every kernel geometry they have seen before.
-    Idempotent and best-effort (a read-only filesystem or an old jax
-    must never fail a check); returns the cache dir, or None when
-    disabled or unavailable. The compile-time floor is dropped to 0 —
-    the walks compile MANY small per-geometry programs whose aggregate
-    recompile cost is the warm-start wall this hides."""
-    p = persist_root(store_root)
-    if p is None:
+def enable_compilation_cache() -> Optional[str]:
+    """Turn on jax's persistent compilation cache so rechecks and fresh
+    processes skip XLA recompiles of every kernel geometry they have
+    seen before: in ``JAX_COMPILATION_CACHE_DIR`` where that is set,
+    else in :data:`XLA_CACHE_DIR`. Idempotent and best-effort (a
+    read-only filesystem must never fail a check); returns the cache
+    dir, or None when disabled or unavailable. The compile-time floor
+    is dropped to 0 — the walks compile MANY small per-geometry
+    programs whose aggregate recompile cost is the warm-start wall this
+    hides — and the tier is bounded at 1 GiB (fuzz/soak mint fresh
+    geometries forever; jax evicts LRU past the bound)."""
+    if os.environ.get("JEPSEN_TPU_NO_PERSIST"):
         return None
-    if store_root:
-        _PERSIST_STATE["root"] = store_root
-    d = os.path.join(p, "xla")
-    if _PERSIST_STATE.get("cc_dir") == d:
-        return d
+    if "cc_dir" in _PERSIST_STATE:
+        return _PERSIST_STATE["cc_dir"]
     try:
-        os.makedirs(d, exist_ok=True)
         import jax
-        jax.config.update("jax_compilation_cache_dir", d)
+        d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        if not d:
+            d = XLA_CACHE_DIR
+            os.makedirs(d, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", d)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-        except Exception:                               # noqa: BLE001
-            pass                        # flag renamed/absent: floor only
-        try:
-            # bound the tier: fuzz/soak mint fresh geometries forever,
-            # and with the floors at 0/-1 every one persists — let jax
-            # evict LRU past 1 GiB instead of growing monotonically
-            jax.config.update("jax_compilation_cache_max_size",
-                              1 << 30)
-        except Exception:                               # noqa: BLE001
-            pass                        # older jax: unbounded, floor-only
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                          -1)
+        jax.config.update("jax_compilation_cache_max_size", 1 << 30)
         _install_compile_cache_metrics()
         _PERSIST_STATE["cc_dir"] = d
         return d
@@ -107,11 +106,11 @@ def _install_compile_cache_metrics() -> None:
     """Translate jax's compilation-cache monitoring events into obs
     counters (``compile_cache.hits`` / ``compile_cache.requests``) so
     bench runs and stored ``obs.jsonl`` show whether a warm start
-    actually skipped recompiles. Internal jax API — best-effort."""
+    actually skipped recompiles. Best-effort."""
     if _PERSIST_STATE.get("metrics"):
         return
     try:
-        from jax._src import monitoring
+        from jax import monitoring
 
         from jepsen_tpu import obs
 
@@ -129,12 +128,12 @@ def _install_compile_cache_metrics() -> None:
 
 def create_run_dir(test: Mapping) -> str:
     root = test.get("store-root", "store")
-    # re-key the persistent caches under THIS run's store root (a test
-    # configured with store-root=/data/runs must not leave its warm
-    # artifacts under ./store/.cache of whatever CWD the process has);
-    # engine entries that fired earlier pointed jax at the default —
-    # the update below re-points it for every later compile
-    enable_compilation_cache(root)
+    # re-key the memo/autotune caches under THIS run's store root (a
+    # test configured with store-root=/data/runs must not leave its
+    # warm artifacts under ./store/.cache of whatever CWD the process
+    # has); the XLA tier stays at its one fixed directory
+    _PERSIST_STATE["root"] = root
+    enable_compilation_cache()
     name = str(test.get("name", "test")).replace("/", "_")
     ts = test.get("start-time") or "run"
     d = os.path.join(root, name, ts)

@@ -1,5 +1,6 @@
 """Persistent warm-start caches (ISSUE 3): the jax compilation-cache
-wiring under the store dir (``store.enable_compilation_cache``), the
+wiring (``store.enable_compilation_cache`` — ``JAX_COMPILATION_CACHE_DIR``
+or one fixed directory in the checkout), the
 disk-backed tier below ``reach._MEMO_CACHE`` with model-signature
 invalidation, and the in-memory memo cache's LRU eviction order +
 ``memo_cache.*`` counters."""
@@ -26,19 +27,28 @@ import jax, jax.numpy as jnp
 f = jax.jit(lambda x: (x @ x.T).sum() * {salt})
 _ = float(f(jnp.arange(12.0).reshape(3, 4)))
 c = obs.counters()
-print(json.dumps({{"dir": d,
+print(json.dumps({{"dir": d, "jax": jax.config.jax_compilation_cache_dir,
                    "hits": c.get("compile_cache.hits", 0),
                    "requests": c.get("compile_cache.requests", 0)}}))
 '''
 
 
-def _run_child(tmp_path, salt, extra_env=None):
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _child_env(extra_env=None):
     env = dict(os.environ)
-    env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
-    env["JEPSEN_TPU_CACHE_DIR"] = str(tmp_path)
+    env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("JEPSEN_TPU_NO_PERSIST", None)   # conftest defaults it on
     env.update(extra_env or {})
+    return env
+
+
+def _run_child(tmp_path, salt, extra_env=None, jax_dir=True):
+    env = _child_env({"JAX_COMPILATION_CACHE_DIR":
+                      str(tmp_path / "xla"), **(extra_env or {})})
+    if not jax_dir:
+        env.pop("JAX_COMPILATION_CACHE_DIR")
     out = subprocess.run(
         [sys.executable, "-c", _CHILD.format(salt=salt)],
         capture_output=True, text=True, env=env, cwd=str(tmp_path),
@@ -49,7 +59,8 @@ def _run_child(tmp_path, salt, extra_env=None):
 
 def test_compile_cache_round_trip_across_processes(tmp_path):
     """A fresh process re-running the same computation hits the
-    persistent compilation cache populated by the first."""
+    persistent compilation cache populated by the first, in the
+    directory ``JAX_COMPILATION_CACHE_DIR`` names."""
     r1 = _run_child(tmp_path, 3)
     assert r1["dir"] == os.path.join(str(tmp_path), "xla")
     assert os.listdir(r1["dir"])             # cache populated
@@ -59,10 +70,48 @@ def test_compile_cache_round_trip_across_processes(tmp_path):
 
 
 def test_compile_cache_opt_out(tmp_path):
-    """JEPSEN_TPU_NO_PERSIST=1 disables the wiring entirely."""
-    r = _run_child(tmp_path, 5, {"JEPSEN_TPU_NO_PERSIST": "1"})
-    assert r["dir"] is None
+    """JEPSEN_TPU_NO_PERSIST=1 disables the wiring entirely: no
+    directory is set or created."""
+    r = _run_child(tmp_path, 5, {"JEPSEN_TPU_NO_PERSIST": "1"},
+                   jax_dir=False)
+    assert r["dir"] is None and r["jax"] is None
     assert not (tmp_path / "xla").exists()
+
+
+_DEFAULT_DIR_CHILD = r'''
+import json, os, sys
+os.environ["JAX_PLATFORMS"] = "cpu"
+from jepsen_tpu import store
+store.create_run_dir({"store-root": sys.argv[1], "name": "t"})
+import jax
+print(json.dumps({"dir": store.enable_compilation_cache(),
+                  "jax": jax.config.jax_compilation_cache_dir,
+                  "fixed": store.XLA_CACHE_DIR}))
+'''
+
+
+def test_compile_cache_default_dir_is_fixed(tmp_path):
+    """Without ``JAX_COMPILATION_CACHE_DIR`` the XLA tier lands in ONE
+    directory inside the checkout, whatever the CWD or the run's store
+    root (the path is part of jax's cache key: a directory that moves
+    never hits)."""
+    dirs = []
+    for sub in ("a", "b"):
+        cwd = tmp_path / sub
+        cwd.mkdir()
+        env = _child_env()
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        out = subprocess.run(
+            [sys.executable, "-c", _DEFAULT_DIR_CHILD,
+             str(tmp_path / f"store-{sub}")],
+            capture_output=True, text=True, env=env, cwd=str(cwd),
+            timeout=300)
+        assert out.returncode == 0, out.stderr
+        r = json.loads(out.stdout.strip().splitlines()[-1])
+        assert r["dir"] == r["jax"] == r["fixed"]
+        dirs.append(r["dir"])
+    assert dirs[0] == dirs[1]
+    assert dirs[0].startswith(_REPO + os.sep)
 
 
 def _clear_memo_state():
