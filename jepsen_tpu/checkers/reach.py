@@ -924,7 +924,8 @@ def _cached_memo(model: Model, packed: h.PackedHistory,
         canonical_ops = tuple(packed.distinct_ops[i] for i in order)
         m = _disk_memo_get(sig, canonical_ops)
         if m is None:
-            m = memo_ops(model, canonical_ops, max_states=max_states)
+            with obs.span("memo.build", ops=len(canonical_ops)):
+                m = memo_ops(model, canonical_ops, max_states=max_states)
             _disk_memo_put(sig, m)
         _cache_put(sig, m)
     else:
@@ -1006,29 +1007,30 @@ def _disk_memo_get(sig, canonical_ops: Tuple[Op, ...]) -> Optional[Memo]:
     if pr is None:
         return None
     path, rep = pr
-    try:
-        with open(path, "rb") as f:
-            payload = pickle.load(f)
-        if (payload.get("sig") != rep
-                or type(payload.get("model")) is not type(sig[0])
-                or payload.get("model") != sig[0]):
-            raise ValueError("memo signature mismatch")
-        m = payload["memo"]
-        m = Memo(table=m.table, states=m.states,
-                 distinct_ops=canonical_ops, initial=m.initial)
-    except FileNotFoundError:
-        obs.count("memo_cache.disk.miss")
-        return None
-    except Exception:                                   # noqa: BLE001
-        obs.count("memo_cache.disk.invalid")
+    with obs.span("memo.disk.get"):
         try:
-            os.unlink(path)             # corrupt/stale entry: drop it
-        # jtlint: ok fallback — absent/unreadable disk entry is a cache miss, counted by the caller
-        except OSError:
-            pass
-        return None
-    obs.count("memo_cache.disk.hit")
-    return m
+            with open(path, "rb") as f:
+                payload = pickle.load(f)
+            if (payload.get("sig") != rep
+                    or type(payload.get("model")) is not type(sig[0])
+                    or payload.get("model") != sig[0]):
+                raise ValueError("memo signature mismatch")
+            m = payload["memo"]
+            m = Memo(table=m.table, states=m.states,
+                     distinct_ops=canonical_ops, initial=m.initial)
+        except FileNotFoundError:
+            obs.count("memo_cache.disk.miss")
+            return None
+        except Exception:                               # noqa: BLE001
+            obs.count("memo_cache.disk.invalid")
+            try:
+                os.unlink(path)         # corrupt/stale entry: drop it
+            # jtlint: ok fallback — absent/unreadable disk entry is a cache miss, counted by the caller
+            except OSError:
+                pass
+            return None
+        obs.count("memo_cache.disk.hit")
+        return m
 
 
 # entry-count cap for the disk memo dir: a fuzz/soak campaign mints a
@@ -1050,28 +1052,35 @@ def _disk_memo_put(sig, m: Memo) -> None:
     if pr is None:
         return
     path, rep = pr
-    try:
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        tmp = f"{path}.tmp.{os.getpid()}"
-        with open(tmp, "wb") as f:
-            pickle.dump({"sig": rep, "model": sig[0], "memo": m}, f)
-        os.replace(tmp, path)
-        obs.count("memo_cache.disk.store")
-        d = os.path.dirname(path)
-        names = [n for n in os.listdir(d) if n.endswith(".memo.pkl")]
-        if len(names) > _DISK_MEMO_MAX_ENTRIES:
-            by_age = sorted(
-                names, key=lambda n: os.path.getmtime(os.path.join(d, n)))
-            for n in by_age[:len(names) - _DISK_MEMO_MAX_ENTRIES]:
-                try:
-                    os.unlink(os.path.join(d, n))
-                    obs.count("memo_cache.disk.evict")
-                # jtlint: ok fallback — best-effort cache store/evict; misses are counted on read
-                except OSError:
-                    pass
-    # jtlint: ok fallback — best-effort cache store/evict; misses are counted on read
-    except Exception:                                   # noqa: BLE001
-        pass
+    with obs.span("memo.disk.put"):
+        try:
+            os.makedirs(os.path.dirname(path), exist_ok=True)
+            tmp = f"{path}.tmp.{os.getpid()}"
+            with open(tmp, "wb") as f:
+                pickle.dump({"sig": rep, "model": sig[0], "memo": m}, f)
+            os.replace(tmp, path)
+            obs.count("memo_cache.disk.store")
+            d = os.path.dirname(path)
+            with obs.span("memo.disk.evict") as sp:
+                names = [n for n in os.listdir(d)
+                         if n.endswith(".memo.pkl")]
+                sp.set("listed", len(names))
+                evicted = 0
+                if len(names) > _DISK_MEMO_MAX_ENTRIES:
+                    by_age = sorted(names, key=lambda n: os.path.getmtime(
+                        os.path.join(d, n)))
+                    for n in by_age[:len(names) - _DISK_MEMO_MAX_ENTRIES]:
+                        try:
+                            os.unlink(os.path.join(d, n))
+                            obs.count("memo_cache.disk.evict")
+                            evicted += 1
+                        # jtlint: ok fallback — best-effort cache store/evict; misses are counted on read
+                        except OSError:
+                            pass
+                sp.set("evicted", evicted)
+        # jtlint: ok fallback — best-effort cache store/evict; misses are counted on read
+        except Exception:                               # noqa: BLE001
+            pass
 
 
 # superset seeds: a few union-alphabet memos with precomputed
@@ -1138,7 +1147,8 @@ def _memo_for_ops(model: Model, ops: Tuple[Op, ...],
     # jtlint: ok fallback — unhashable values: superset seeding skipped, exact path intact
     except TypeError:
         pass
-    return memo_ops(model, ops, max_states=max_states)
+    with obs.span("memo.build", ops=len(ops)):
+        return memo_ops(model, ops, max_states=max_states)
 
 
 def _seed_union_memo(model: Model,
@@ -1165,8 +1175,9 @@ def _seed_union_memo(model: Model,
             if sig in _SUPERSET_SEEDS or sig in _SUPERSET_SEEDS_FAILED:
                 return
         ops = tuple(union[keys[i]] for i in order)
-        m = memo_ops(model, ops,
-                     max_states=min(max_states, _SUPERSET_MAX_STATES))
+        with obs.span("memo.build", ops=len(ops)):
+            m = memo_ops(model, ops,
+                         max_states=min(max_states, _SUPERSET_MAX_STATES))
     # jtlint: ok fallback — decline tracked in _SUPERSET_SEEDS_FAILED; per-key path decides
     except StateExplosion:
         with _MEMO_CACHE_LOCK:
@@ -1430,8 +1441,9 @@ def check_packed(model: Model, packed: h.PackedHistory, *,
                 out = _result_invalid(
                     "reach-word", stream, memo, packed,
                     int(rs.ret_event[dead]), elapsed)
-                _attach_witness(out, memo, rs, _build_P(memo, S_pad),
-                                S_pad, M, W, int(dead), packed)
+                with obs.span("reach.witness", engine="reach-word"):
+                    _attach_witness(out, memo, rs, _build_P(memo, S_pad),
+                                    S_pad, M, W, int(dead), packed)
                 return out
             except Exception as e:                      # noqa: BLE001
                 # exactly one record; the pallas/dense chain below is
@@ -1462,8 +1474,10 @@ def check_packed(model: Model, packed: h.PackedHistory, *,
                         "reach-chunklock", stream, memo, packed,
                         int(rs.ret_event[dead]), elapsed)
                     out.update(diag)
-                    _attach_witness(out, memo, rs, P_np, S_pad, M,
-                                    W, int(dead), packed)
+                    with obs.span("reach.witness",
+                                  engine="reach-chunklock"):
+                        _attach_witness(out, memo, rs, P_np, S_pad, M,
+                                        W, int(dead), packed)
                     return out
                 except Exception as e:                  # noqa: BLE001
                     _warn_pallas_failed(f"chunklock: {e!r}")
@@ -1505,8 +1519,9 @@ def check_packed(model: Model, packed: h.PackedHistory, *,
                                          elapsed)
                 out = _result_invalid("reach-pallas", stream, memo, packed,
                                       int(rs.ret_event[dead]), elapsed)
-                _attach_witness(out, memo, rs, P_np, S_pad, M, W,
-                                int(dead), packed)
+                with obs.span("reach.witness", engine="reach-pallas"):
+                    _attach_witness(out, memo, rs, P_np, S_pad, M, W,
+                                    int(dead), packed)
                 return out
         rs = ev.pad_returns(rs, max(64, _bucket(rs.n_returns, _UNROLL)))
         P = jnp.asarray(P_np)
@@ -1530,21 +1545,24 @@ def check_packed(model: Model, packed: h.PackedHistory, *,
                     break
                 base += seg
         else:
+            # the span closes on the verdict, not on the dispatch
             with obs.span("reach.walk", engine="reach",
                           returns=int(rs.n_returns)):
                 ptr, _, alive, R_block = _jitted_walk_returns()(
                     P, xc, bm, jnp.asarray(rs.ret_slot),
                     jnp.asarray(rs.slot_ops), R0)
+                alive = bool(alive)
         elapsed = _time.monotonic() - t0
         if bool(alive):
             return _result_valid("reach", stream, memo, elapsed)
-        dead_event = _refine_dead(P, xc, bm, rs, int(ptr), R_block)
-        out = _result_invalid("reach", stream, memo, packed, dead_event,
-                              elapsed)
-        dead_ret = int(np.searchsorted(rs.ret_event[:rs.n_returns],
-                                       dead_event))
-        _attach_witness(out, memo, rs, P_np, S_pad, M, W, dead_ret,
-                        packed)
+        with obs.span("reach.witness", engine="reach"):
+            dead_event = _refine_dead(P, xc, bm, rs, int(ptr), R_block)
+            out = _result_invalid("reach", stream, memo, packed,
+                                  dead_event, elapsed)
+            dead_ret = int(np.searchsorted(rs.ret_event[:rs.n_returns],
+                                           dead_event))
+            _attach_witness(out, memo, rs, P_np, S_pad, M, W, dead_ret,
+                            packed)
         return out
     R0 = jnp.zeros((S_pad, M), jnp.bool_).at[0, 0].set(True)
     slot_op0 = jnp.full((W,), -1, jnp.int32)
@@ -1554,13 +1572,15 @@ def check_packed(model: Model, packed: h.PackedHistory, *,
             jnp.asarray(T), jnp.asarray(stream.kind),
             jnp.asarray(stream.slot), jnp.asarray(stream.opid), R0,
             slot_op0)
+        alive = bool(alive)
     elapsed = _time.monotonic() - t0
-    if bool(alive):
+    if alive:
         return _result_valid("reach", stream, memo, elapsed)
     out = _result_invalid("reach", stream, memo, packed,
                           int(ptr) - 1, elapsed)
-    _attach_witness_slow(out, memo, stream, T, S_pad, M, W,
-                         int(ptr) - 1, packed)
+    with obs.span("reach.witness", engine="reach-events"):
+        _attach_witness_slow(out, memo, stream, T, S_pad, M, W,
+                             int(ptr) - 1, packed)
     return out
 
 
@@ -1666,9 +1686,10 @@ def _check_many_keyed(model, rss, preps, live, results, packed_list,
             # (wide[k] carries union op ids the per-key memo can't name)
             rs_k = ev.returns_view(stream)
             W_k = max(stream.W, 1)
-            _attach_witness(results[i], memo, rs_k,
-                            _build_P(memo, preps[i][3]), preps[i][3],
-                            1 << W_k, W_k, local, packed_list[i])
+            with obs.span("reach.witness", engine="reach-keyed"):
+                _attach_witness(results[i], memo, rs_k,
+                                _build_P(memo, preps[i][3]), preps[i][3],
+                                1 << W_k, W_k, local, packed_list[i])
     return results
 
 
@@ -1733,35 +1754,36 @@ def _union_stage_a(model: Model,
                    max_states: int) -> Optional["_UnionPrepA"]:
     """Build stage A, or None when the union alphabet explodes or ops
     are unhashable (callers fall back to per-history paths)."""
-    union: Dict[Any, int] = {}
-    union_ops: List[Op] = []
-    try:
+    with obs.span("union.stage_a", histories=len(live)):
+        union: Dict[Any, int] = {}
+        union_ops: List[Op] = []
+        try:
+            for i in live:
+                p = packed_list[i]
+                for key, op in zip(h.op_keys_of(p), p.distinct_ops):
+                    if key not in union:
+                        union[key] = len(union_ops)
+                        union_ops.append(op)
+            memo_u = _memo_for_ops(model, tuple(union_ops),
+                                   max_states=max_states)
+        # jtlint: ok fallback — batch-capability probe: None routes to per-key, which records
+        except (StateExplosion, TypeError):
+            return None
+        S_pad = max(2, _next_pow2(memo_u.n_states))
+        tbl = memo_u.table
+        states = np.arange(tbl.shape[0], dtype=tbl.dtype)[:, None]
+        noop_op = np.all((tbl == states) | (tbl == -1), axis=0)
+        opids, invs, rets, crs = [], [], [], []
         for i in live:
             p = packed_list[i]
-            for key, op in zip(h.op_keys_of(p), p.distinct_ops):
-                if key not in union:
-                    union[key] = len(union_ops)
-                    union_ops.append(op)
-        memo_u = _memo_for_ops(model, tuple(union_ops),
-                               max_states=max_states)
-    # jtlint: ok fallback — batch-capability probe: None routes to per-key, which records
-    except (StateExplosion, TypeError):
-        return None
-    S_pad = max(2, _next_pow2(memo_u.n_states))
-    tbl = memo_u.table
-    states = np.arange(tbl.shape[0], dtype=tbl.dtype)[:, None]
-    noop_op = np.all((tbl == states) | (tbl == -1), axis=0)
-    opids, invs, rets, crs = [], [], [], []
-    for i in live:
-        p = packed_list[i]
-        keys = h.op_keys_of(p)
-        lut = np.fromiter((union[k] for k in keys), np.int32,
-                          count=len(keys))
-        opids.append(lut[p.op_id])
-        invs.append(p.inv_ev)
-        rets.append(p.ret_ev)
-        crs.append(p.crashed)
-    return _UnionPrepA(memo_u, S_pad, noop_op, opids, invs, rets, crs)
+            keys = h.op_keys_of(p)
+            lut = np.fromiter((union[k] for k in keys), np.int32,
+                              count=len(keys))
+            opids.append(lut[p.op_id])
+            invs.append(p.inv_ev)
+            rets.append(p.ret_ev)
+            crs.append(p.crashed)
+        return _UnionPrepA(memo_u, S_pad, noop_op, opids, invs, rets, crs)
 
 
 def _union_pack_group(sa: "_UnionPrepA", sel: Sequence[int],
@@ -2149,17 +2171,18 @@ def _union_results_parts(engine: str, model: Model,
                 memo_u.n_states, elapsed)
         else:
             local = int(dead_local[k])
-            memo_k, stream_k, _Tk, S_k, M_k = _prep(
-                model, p, max_states=max_states, max_slots=max_slots,
-                max_dense=max_dense)
-            rs_k = ev.returns_view(stream_k)
-            W_k = max(stream_k.W, 1)
-            results[i] = _result_invalid(
-                engine, stream_k, memo_k, p,
-                int(rs_k.ret_event[local]), elapsed)
-            _attach_witness(results[i], memo_k, rs_k,
-                            _build_P(memo_k, S_k), S_k, M_k, W_k,
-                            local, p)
+            with obs.span("reach.witness", engine=engine):
+                memo_k, stream_k, _Tk, S_k, M_k = _prep(
+                    model, p, max_states=max_states, max_slots=max_slots,
+                    max_dense=max_dense)
+                rs_k = ev.returns_view(stream_k)
+                W_k = max(stream_k.W, 1)
+                results[i] = _result_invalid(
+                    engine, stream_k, memo_k, p,
+                    int(rs_k.ret_event[local]), elapsed)
+                _attach_witness(results[i], memo_k, rs_k,
+                                _build_P(memo_k, S_k), S_k, M_k, W_k,
+                                local, p)
     return results  # type: ignore[return-value]
 
 
@@ -2205,7 +2228,6 @@ def _lockstep_accounting(gdiags: List[dict], prep_s: float,
     obs.gauge("lockstep.pack_efficiency", round(real / max(padded, 1), 4))
     obs.gauge("lockstep.kernel_cache.hits", cache["hits"])
     obs.gauge("lockstep.kernel_cache.misses", cache["misses"])
-    obs.gauge("lockstep.kernel_cache.entries", cache["entries"])
     obs.gauge("prep.wall_s", round(prep_s, 6))
     obs.gauge("prep.hidden_s", round(hidden_s, 6))
     obs.gauge("prep.stall_s", round(stall_s, 6))
@@ -3117,19 +3139,20 @@ def _vmapped_fast_launch(preps, live, results, rss, packed_list,
             else:
                 if R_blocks_np is None:
                     R_blocks_np = _fetch(R_blocks)
-                Pk = (jnp.asarray(Ps[0]) if shared
-                      else jnp.asarray(Ps_np[k]))
-                dead_event = _refine_dead(Pk, xc, bm, rss[k],
-                                          int(ptrs_np[k]),
-                                          jnp.asarray(R_blocks_np[k]))
-                results[i] = _result_invalid(
-                    "reach-batch", stream, memo, packed_list[i],
-                    dead_event, elapsed)
-                dead_ret = int(np.searchsorted(
-                    rss[k].ret_event[:rss[k].n_returns], dead_event))
-                _attach_witness(results[i], memo, rss[k],
-                                Ps[k], S_pad, M, W, dead_ret,
-                                packed_list[i])
+                with obs.span("reach.witness", engine="reach-batch"):
+                    Pk = (jnp.asarray(Ps[0]) if shared
+                          else jnp.asarray(Ps_np[k]))
+                    dead_event = _refine_dead(Pk, xc, bm, rss[k],
+                                              int(ptrs_np[k]),
+                                              jnp.asarray(R_blocks_np[k]))
+                    results[i] = _result_invalid(
+                        "reach-batch", stream, memo, packed_list[i],
+                        dead_event, elapsed)
+                    dead_ret = int(np.searchsorted(
+                        rss[k].ret_event[:rss[k].n_returns], dead_event))
+                    _attach_witness(results[i], memo, rss[k],
+                                    Ps[k], S_pad, M, W, dead_ret,
+                                    packed_list[i])
         return results  # type: ignore[return-value]
 
     return StagedVmapped([ptrs, alives], _collect)
@@ -3291,9 +3314,10 @@ def check_many(model: Model, packed_list: Sequence[h.PackedHistory], *,
                 results[i] = _result_invalid(
                     "reach-batch", stream, memo, packed_list[i],
                     int(ptrs[k]) - 1, elapsed)
-                _attach_witness_slow(results[i], memo, stream, Ts[k],
-                                     S_pad, M, W, int(ptrs[k]) - 1,
-                                     packed_list[i])
+                with obs.span("reach.witness", engine="reach-batch"):
+                    _attach_witness_slow(results[i], memo, stream, Ts[k],
+                                         S_pad, M, W, int(ptrs[k]) - 1,
+                                         packed_list[i])
     return results  # type: ignore[return-value]
 
 

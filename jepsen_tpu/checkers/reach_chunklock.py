@@ -662,8 +662,9 @@ def check_packed(model, packed, *, max_states: int = 100_000,
         out = reach._result_invalid("reach-chunklock", stream, memo,
                                     packed, int(rs.ret_event[dead]),
                                     elapsed)
-        reach._attach_witness(out, memo, rs, P_np, S_pad, M, W,
-                              int(dead), packed)
+        with obs.span("reach.witness", engine="reach-chunklock"):
+            reach._attach_witness(out, memo, rs, P_np, S_pad, M, W,
+                                  int(dead), packed)
     out.update(diag)
     return out
 

@@ -26,13 +26,25 @@ Design constraints (ISSUE 2):
 - **Bounded.** Span and ledger stores are capped; drops are themselves
   counted (``obs.dropped.spans`` / ``obs.dropped.ledger``) so a capped
   export is never mistaken for a complete one.
+- **Parented.** Every span records ``id``, ``parent`` (the innermost
+  open span of the same ``contextvars`` context, 0 at a root) and
+  ``root`` in its args, so threads run under ``copy_context()`` nest
+  under the span that spawned them and a request's work can be summed
+  under its root.
+- **On the profiler's clock.** While a ``jax.profiler`` session is
+  active, each span also enters a ``TraceAnnotation`` of its name, so
+  the program's spans sit on the trace's host plane beside the device
+  ops. ``jax`` is never imported here: the bridge looks for it in
+  ``sys.modules``.
 """
 from __future__ import annotations
 
 import bisect
 import contextvars
+import itertools
 import math
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -162,12 +174,43 @@ def enabled() -> bool:
 
 # -- spans ---------------------------------------------------------------
 
+_SPAN_IDS = itertools.count(1)
+
+# (id, root) of the innermost open span of this context; (0, 0) outside
+# any. A ContextVar for the same reason as _CAPTURES: threads run under
+# a copied context nest under the span that spawned them.
+_OPEN: "contextvars.ContextVar[Tuple[int, int]]" = \
+    contextvars.ContextVar("jepsen_tpu_obs_open", default=(0, 0))
+
+# jax.profiler.TraceAnnotation, looked up once jax has been imported
+_ANNOTATION: Any = None
+
+
+def _annotation(name: str):
+    """An entered ``TraceAnnotation`` named ``name`` while a profiler
+    session is active, else None (one ``is_enabled`` call)."""
+    global _ANNOTATION
+    cls = _ANNOTATION
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+        cls = _ANNOTATION = TraceAnnotation
+    if not cls.is_enabled():
+        return None
+    ann = cls(name)
+    ann.__enter__()
+    return ann
+
+
 class Span:
     """Context manager recording one Chrome-trace ``"X"`` (complete)
     event on exit. ``set(key, value)`` adds args mid-flight (e.g. the
-    engine a check ultimately selected)."""
+    engine a check ultimately selected). The event's args carry the
+    span's ``id``, its ``parent`` and its ``root``."""
 
-    __slots__ = ("name", "cat", "args", "_ts")
+    __slots__ = ("name", "cat", "args", "_ts", "_id", "_parent", "_root",
+                 "_token", "_ann")
 
     def __init__(self, name: str, cat: str = "",
                  args: Optional[Dict[str, Any]] = None):
@@ -181,22 +224,33 @@ class Span:
         self.args[key] = value
 
     def __enter__(self) -> "Span":
+        parent, root = _OPEN.get()
+        self._id = sid = next(_SPAN_IDS)
+        self._parent, self._root = parent, root or sid
+        self._token = _OPEN.set((sid, self._root))
+        self._ann = _annotation(self.name)
         self._ts = _now_us()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         end = _now_us()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        try:
+            _OPEN.reset(self._token)
+        except ValueError:      # exited in a context that never saw it open
+            pass
+        args = self.args if self.args is not None else {}
+        args.update(id=self._id, parent=self._parent, root=self._root)
+        if exc_type is not None:
+            args["error"] = exc_type.__name__
         ev: Dict[str, Any] = {
             "name": self.name, "ph": "X", "ts": self._ts,
             "dur": end - self._ts, "pid": os.getpid(),
-            "tid": threading.get_ident(),
+            "tid": threading.get_ident(), "args": args,
         }
         if self.cat:
             ev["cat"] = self.cat
-        if self.args:
-            ev["args"] = self.args
-        if exc_type is not None:
-            ev.setdefault("args", {})["error"] = exc_type.__name__
         for s in _sinks():
             s.add_span(ev)
 

@@ -570,7 +570,8 @@ class Dispatcher:
             # iteration (even a failed attempt spent device time)
             lane.device_ran = True
             with obs.span("serve.dispatch",
-                          model=req0.model_name, lanes=len(batch)):
+                          model=req0.model_name, lanes=len(batch),
+                          reqs=[r.id for r in batch]):
                 if self._is_txn(req0.model):
                     # one txn chain per member: host dependency
                     # inference is per-history; the closure kernel
@@ -609,7 +610,8 @@ class Dispatcher:
         req0 = batch[0]
         out = []
         with obs.span("serve.dispatch-host",
-                      model=req0.model_name, lanes=len(batch)):
+                      model=req0.model_name, lanes=len(batch),
+                      reqs=[r.id for r in batch]):
             for r in batch:
                 if self._is_txn(r.model):
                     res = facade.auto_check_txn(
@@ -1151,7 +1153,7 @@ class Dispatcher:
         packed_list, _pad = self._padded_list(batch)
         with obs.capture() as cap:
             with obs.span("pipeline.stage", model=req0.model_name,
-                          lanes=len(batch)):
+                          lanes=len(batch), reqs=[r.id for r in batch]):
                 handle = facade.stage_check_many_packed(
                     req0.model, packed_list, kw)
         if handle is None:
@@ -1193,7 +1195,8 @@ class Dispatcher:
             try:
                 with obs.span("serve.dispatch",
                               model=req0.model_name,
-                              lanes=len(batch)):
+                              lanes=len(batch),
+                              reqs=[r.id for r in batch]):
                     results = staged.handle.collect()[:len(batch)]
                 lane.breaker.record_success()
             except Exception as e:                      # noqa: BLE001
