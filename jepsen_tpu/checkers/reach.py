@@ -1038,12 +1038,23 @@ def _disk_memo_get(sig, canonical_ops: Tuple[Op, ...]) -> Optional[Memo]:
 # else ever deletes them — evict oldest-mtime past the cap on store
 _DISK_MEMO_MAX_ENTRIES = 512
 
+# per tier directory: entries this process left there at its last
+# eviction scan, plus its stores since (see _disk_memo_tally)
+_DISK_MEMO_TALLY: Dict[str, int] = {}
+_DISK_MEMO_TALLY_LOCK = threading.Lock()
+
+
+def _disk_memo_evict_slack() -> int:
+    """Stores past the cap that a process lets through before it scans
+    again: an eighth of the cap, at least 1."""
+    return max(1, _DISK_MEMO_MAX_ENTRIES // 8)
+
 
 def _disk_memo_put(sig, m: Memo) -> None:
     """Best-effort insert into the disk tier (atomic rename; a full or
-    read-only disk must never fail a check). Bounded: past
-    ``_DISK_MEMO_MAX_ENTRIES`` the oldest entries are evicted, so a
-    long soak cannot grow the tier monotonically."""
+    read-only disk must never fail a check). Bounded by
+    :func:`_disk_memo_tally`, so a long soak cannot grow the tier
+    monotonically."""
     import pickle
     if (m.table.nbytes > _MEMO_CACHE_MAX_ENTRY_BYTES
             or m.n_states > _MEMO_CACHE_MAX_ENTRY_STATES):
@@ -1060,27 +1071,50 @@ def _disk_memo_put(sig, m: Memo) -> None:
                 pickle.dump({"sig": rep, "model": sig[0], "memo": m}, f)
             os.replace(tmp, path)
             obs.count("memo_cache.disk.store")
-            d = os.path.dirname(path)
-            with obs.span("memo.disk.evict") as sp:
-                names = [n for n in os.listdir(d)
-                         if n.endswith(".memo.pkl")]
-                sp.set("listed", len(names))
-                evicted = 0
-                if len(names) > _DISK_MEMO_MAX_ENTRIES:
-                    by_age = sorted(names, key=lambda n: os.path.getmtime(
-                        os.path.join(d, n)))
-                    for n in by_age[:len(names) - _DISK_MEMO_MAX_ENTRIES]:
-                        try:
-                            os.unlink(os.path.join(d, n))
-                            obs.count("memo_cache.disk.evict")
-                            evicted += 1
-                        # jtlint: ok fallback — best-effort cache store/evict; misses are counted on read
-                        except OSError:
-                            pass
-                sp.set("evicted", evicted)
+            _disk_memo_tally(os.path.dirname(path))
         # jtlint: ok fallback — best-effort cache store/evict; misses are counted on read
         except Exception:                               # noqa: BLE001
             pass
+
+
+def _disk_memo_tally(d: str) -> None:
+    """Count one store into tier directory ``d`` and keep the tier
+    bounded. A scan (one ``listdir`` and a stat of every entry) costs
+    tens of times the store it guards, so it is amortised: the first
+    store to ``d`` in a process scans, evicting oldest-mtime entries
+    down to ``_DISK_MEMO_MAX_ENTRIES``; later stores add one to the
+    tally, and the store that takes it past cap + slack
+    (:func:`_disk_memo_evict_slack`) scans again, back down to the cap.
+    So one process never lets the tier hold more than cap + slack
+    entries. Processes that share a store root (fleet replicas) each
+    evict the others' entries at their own next scan, so the tier stays
+    within cap + slack per process. The scan runs under the lock: two
+    threads never scan at once, and none stores past the bound while
+    one does."""
+    with _DISK_MEMO_TALLY_LOCK:
+        held = _DISK_MEMO_TALLY.get(d)
+        if (held is not None
+                and held < _DISK_MEMO_MAX_ENTRIES + _disk_memo_evict_slack()):
+            _DISK_MEMO_TALLY[d] = held + 1
+            return
+        obs.count("memo_cache.disk.scan")
+        with obs.span("memo.disk.evict") as sp:
+            names = [n for n in os.listdir(d) if n.endswith(".memo.pkl")]
+            sp.set("listed", len(names))
+            evicted = 0
+            if len(names) > _DISK_MEMO_MAX_ENTRIES:
+                by_age = sorted(names, key=lambda n: os.path.getmtime(
+                    os.path.join(d, n)))
+                for n in by_age[:len(names) - _DISK_MEMO_MAX_ENTRIES]:
+                    try:
+                        os.unlink(os.path.join(d, n))
+                        obs.count("memo_cache.disk.evict")
+                        evicted += 1
+                    # jtlint: ok fallback — best-effort cache store/evict; misses are counted on read
+                    except OSError:
+                        pass
+            sp.set("evicted", evicted)
+            _DISK_MEMO_TALLY[d] = len(names) - evicted
 
 
 # superset seeds: a few union-alphabet memos with precomputed

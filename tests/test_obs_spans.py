@@ -248,6 +248,82 @@ def test_disk_evict_span_counts_what_it_listed_and_evicted(tmp_path,
     assert len(os.listdir(d)) == 3
 
 
+def _synthetic_put(k):
+    """Store a one-state memo under a signature of its own; return the
+    entry's path, backdated to mtime ``k + 1`` so store order is the
+    mtime order whatever the file system's clock resolution."""
+    from jepsen_tpu.models.memo import Memo
+    model = models.cas_register()
+    sig = (model, 100_000, (("synthetic", k),))
+    reach._disk_memo_put(sig, Memo(table=np.zeros((1, 1), np.int32),
+                                   states=(model,), distinct_ops=()))
+    path = reach._disk_memo_path(sig)[0]
+    os.utime(path, (k + 1, k + 1))
+    return path
+
+
+def _disk_tier_small(monkeypatch, tmp_path):
+    """Cap 8, so slack 1; a fresh per-process tally."""
+    _persist_on(monkeypatch, tmp_path)
+    monkeypatch.setattr(reach, "_DISK_MEMO_MAX_ENTRIES", 8)
+    monkeypatch.setattr(reach, "_DISK_MEMO_TALLY", {})
+    assert reach._disk_memo_evict_slack() == 1
+    return os.path.join(str(tmp_path), "memo")
+
+
+def test_disk_evict_scan_is_amortised_over_slack_stores(tmp_path,
+                                                        monkeypatch):
+    d = _disk_tier_small(monkeypatch, tmp_path)
+    paths = []
+    with obs.capture() as cap:
+        for k in range(20):
+            paths.append(_synthetic_put(k))
+            assert len(os.listdir(d)) <= 8 + 1
+            newest = paths[-8:]
+            assert all(os.path.exists(p) for p in newest), k
+    # the first store scans; the tally then reaches cap + slack = 9 at
+    # the 9th store, and from the 10th on every 2nd store passes it
+    scans = [0, 9, 11, 13, 15, 17, 19]
+    assert cap.counters["memo_cache.disk.store"] == 20
+    assert cap.counters["memo_cache.disk.scan"] == len(scans)
+    ev = _evict_spans(cap.spans)
+    assert [(e["args"]["listed"], e["args"]["evicted"]) for e in ev] == \
+        [(1, 0)] + [(10, 2)] * 6
+    assert cap.counters["memo_cache.disk.evict"] == 12
+    assert sorted(os.listdir(d)) == sorted(os.path.basename(p)
+                                           for p in paths[-8:])
+
+
+def test_disk_evict_scan_catches_another_process_entries(tmp_path,
+                                                         monkeypatch):
+    """Entries written straight into the directory (another process
+    sharing the store root) are not in this process's tally; its next
+    scan lists and evicts them, oldest first."""
+    d = _disk_tier_small(monkeypatch, tmp_path)
+    ours = [_synthetic_put(0)]
+    foreign = []
+    for k in range(12):
+        path = os.path.join(d, f"other-process-{k}.memo.pkl")
+        open(path, "wb").close()
+        os.utime(path, (0, 0))
+        foreign.append(path)
+    with obs.capture() as cap:
+        for k in range(1, 10):
+            ours.append(_synthetic_put(k))
+    (ev,) = _evict_spans(cap.spans)
+    assert ev["args"]["listed"] == 22 and ev["args"]["evicted"] == 14
+    assert not any(os.path.exists(p) for p in foreign)
+    assert sorted(os.listdir(d)) == sorted(os.path.basename(p)
+                                           for p in ours[-8:])
+
+
+def _evict_spans(spans):
+    ev = _by_name(spans)["memo.disk.evict"]
+    for e in ev:
+        assert _ancestors(e, spans)[0] == "memo.disk.put"
+    return ev
+
+
 # -- serve roots ---------------------------------------------------------
 
 def test_one_shot_request_spans_share_the_dispatch_root():
