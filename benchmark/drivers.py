@@ -2,6 +2,10 @@
 mix's ``entry``. Each driver builds its inputs from the seed during
 set-up, warms the cell's shapes, runs the window, and afterwards hands
 the harness its records and the sample that the reference checks.
+What a driver knows of the histories themselves (how they are made,
+corrupted, relabelled, packed, counted and checked) comes from the
+configuration's history family, ``benchmark/families/<family>.py``
+(``cas`` when the configuration names none).
 
 - ``check_packed``: closed loop, one history in flight, through
   ``reach.check_packed``;
@@ -12,6 +16,7 @@ the harness its records and the sample that the reference checks.
 """
 from __future__ import annotations
 
+import importlib.util
 import json
 import math
 import os
@@ -23,22 +28,23 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from benchmark import gen, reference, sut, work
+from benchmark import sut, work
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+DEFAULT_FAMILY = "cas"
 
 
-def _corrupt_at(rng, h: "gen.History", frac) -> "gen.History":
-    """Corrupt the successful read nearest ``frac`` of the way through
-    the history (a fixed place, so that every seed does the same work
-    before the failure), or a random one when ``frac`` is None."""
-    if frac is None:
-        return gen.corrupt(rng, h)
-    reads = np.flatnonzero((h.f == gen.READ) & h.ok)
-    i = int(reads[np.argmin(np.abs(reads - frac * h.n_ops))])
-    a = h.a.copy()
-    a[i] = gen.BAD
-    return gen.History(h.proc, h.f, a, h.b, h.ok, h.inv, h.ret)
+def load_family(name: str):
+    """The history family ``name``: the module
+    ``benchmark/families/<name>.py``, loaded by path (a name is a path
+    relative to that directory, without ``.py``)."""
+    path = os.path.join(HERE, "families", name + ".py")
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_family_" + "".join(
+            c if c.isalnum() else "_" for c in name), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _perm_order(rng, values: int) -> List[int]:
@@ -53,6 +59,7 @@ class Driver:
     def __init__(self, run) -> None:
         self.run = run
         self.cfg, self.mix = run.config, run.traffic
+        self.fam = load_family(self.cfg.get("family", DEFAULT_FAMILY))
         self.rng = np.random.default_rng(run.seed)
         self.records: List[Dict[str, Any]] = []
         self.window_s = 0.0
@@ -94,21 +101,21 @@ class CheckPacked(Driver):
         n, pool = int(m["ops"]), int(m["pool"])
         self.bases = []
         for i in range(pool):
-            h = gen.cas_history(self.rng, n, c["processes"], c["values"])
+            h = self.fam.history(self.rng, c, n)
             if i in m["corrupted"]:
-                h = _corrupt_at(self.rng, h, m.get("corrupt_at"))
+                h = self.fam.corrupt_at(self.rng, h, m.get("corrupt_at"))
             self.bases.append(h)
-        self.packed = [sut.Packed(h) for h in self.bases]
-        self.work = [work.of_history(h) for h in self.bases]
+        self.packed = [self.fam.packer(h) for h in self.bases]
+        self.work = [self.fam.work(h) for h in self.bases]
         self.perms = _perm_order(self.rng, c["values"])
         for p in self.packed:                        # the cell's shapes
-            sut.check_packed(p.packed(None))
+            self.fam.check_packed(p.packed(None))
 
     def _perm(self, i: int):
         pool = len(self.bases)
         k = i // pool
-        return gen.perm_of(self.perms[k % len(self.perms)],
-                           self.cfg["values"])
+        return self.fam.perm_of(self.perms[k % len(self.perms)],
+                                self.cfg["values"])
 
     def window(self, seconds: float, annotate) -> None:
         pool = len(self.bases)
@@ -122,7 +129,7 @@ class CheckPacked(Driver):
             with annotate("bench.check"):
                 t0 = time.perf_counter()
                 try:
-                    res = sut.check_packed(packed)
+                    res = self.fam.check_packed(packed)
                 except Exception as e:                  # noqa: BLE001
                     res = {"error": repr(e)}
                 t1 = time.perf_counter()
@@ -150,7 +157,7 @@ class CheckPacked(Driver):
         out = []
         for i in pick:
             r = self.records[i]
-            out.append((gen.relabel(self.bases[r["base"]], r["perm"]),
+            out.append((self.fam.relabel(self.bases[r["base"]], r["perm"]),
                         r["answer"]))
         return out
 
@@ -167,28 +174,26 @@ class CheckMany(Driver):
         n_keys = int(c["keys_per_chip"]) * self.run.chips
         self.bases = []
         for k in range(n_keys):
-            h = gen.cas_history(self.rng, int(c["ops_per_key"]),
-                                c["processes"], c["values"])
+            h = self.fam.history(self.rng, c, int(c["ops_per_key"]))
             if k % int(c["corrupt_every"]) == int(c["corrupt_offset"]):
-                h = _corrupt_at(self.rng, h, None)
+                h = self.fam.corrupt_at(self.rng, h, None)
             self.bases.append(h)
-        self.packed = [sut.Packed(h) for h in self.bases]
-        self.work = {}
+        self.packed = [self.fam.packer(h) for h in self.bases]
         w: Dict[str, float] = {}
         for h in self.bases:
-            w = work.add(w, work.of_history(h))
+            w = work.add(w, self.fam.work(h))
         self.work = w
         self.devices = self.run.devices if self.run.chips > 1 else None
         perms = _perm_order(self.rng, c["values"])
         self.batches = []
         for j in range(int(m["pool_batches"]) + int(m["warmup"])):
             shift = int(self.rng.integers(0, n_keys))
-            perm = gen.perm_of(perms[j % len(perms)], c["values"])
+            perm = self.fam.perm_of(perms[j % len(perms)], c["values"])
             order = [(k + shift) % n_keys for k in range(n_keys)]
             self.batches.append((order, perm, [
                 self.packed[k].packed(perm) for k in order]))
         for j in range(int(m["warmup"])):
-            sut.check_many(self.batches.pop()[2], devices=self.devices)
+            self.fam.check_many(self.batches.pop()[2], devices=self.devices)
 
     def window(self, seconds: float, annotate) -> None:
         t_start = time.perf_counter()
@@ -204,8 +209,8 @@ class CheckMany(Driver):
             with annotate("bench.check"):
                 t0 = time.perf_counter()
                 try:
-                    res = sut.check_many(batch, devices=self.devices,
-                                         diag=diag)
+                    res = self.fam.check_many(batch, devices=self.devices,
+                                              diag=diag)
                 except Exception as e:                  # noqa: BLE001
                     res = [{"error": repr(e)}] * len(batch)
                 t1 = time.perf_counter()
@@ -239,7 +244,7 @@ class CheckMany(Driver):
                 while r["order"][pos] % every != off:
                     pos = (pos + 1) % len(r["order"])
             k = r["order"][pos]
-            out.append((gen.relabel(self.bases[k], r["perm"]),
+            out.append((self.fam.relabel(self.bases[k], r["perm"]),
                         r["answers"][pos]))
         return out
 
@@ -275,17 +280,16 @@ class Serve(Driver):
         out, due = [], 0.0
         for i in range(n):
             due += float(gaps[i])
-            h = gen.cas_history(self.rng, int(sizes[order[i]]),
-                                c["processes"], c["values"])
+            h = self.fam.history(self.rng, c, int(sizes[order[i]]))
             if bad[order[i]]:
-                h = _corrupt_at(self.rng, h, m["corrupt_at"])
+                h = self.fam.corrupt_at(self.rng, h, m["corrupt_at"])
             out.append((due, h, bool(bad[order[i]])))
         return out
 
-    def _body(self, i: int, h: "gen.History") -> str:
-        return json.dumps({"model": "cas-register",
+    def _body(self, i: int, h) -> str:
+        return json.dumps({"model": self.fam.model_name,
                            "tenant": f"t{i % int(self.mix['tenants'])}",
-                           "history": gen.to_ops(h)})
+                           "history": self.fam.to_ops(h)})
 
     def setup(self) -> None:
         """Start the daemon, then warm it with every request of the
@@ -304,9 +308,9 @@ class Serve(Driver):
         self.bad = [b for _t, _h, b in reqs]
         perms = _perm_order(self.rng, self.cfg["values"])
         for i, (_t, _b, h) in enumerate(self.reqs):
-            body = self._body(i, gen.relabel(
-                h, gen.perm_of(perms[i % len(perms)],
-                               self.cfg["values"])))
+            body = self._body(i, self.fam.relabel(
+                h, self.fam.perm_of(perms[i % len(perms)],
+                                    self.cfg["values"])))
             self._wait([{"id": _post(self.url, body)}],
                        float(m["drain_s"]))
 
@@ -400,18 +404,19 @@ DRIVERS = {"check_packed": CheckPacked, "check_many": CheckMany,
            "serve": Serve}
 
 
-def compare(pairs: List[tuple], control: bool = False) -> Dict[str, int]:
+def compare(fam, pairs: List[tuple],
+            control: bool = False) -> Dict[str, int]:
     """The numbers that decide ``correct``: how many sampled histories
-    the program answered with another verdict than the reference, and
-    how many invalid ones with another failing op. ``control`` runs the
-    reference with its failing-op guarantee broken in the program's
-    place (see ``reference.check``)."""
+    the program answered with another verdict than the reference (the
+    family's ``check``), and how many invalid ones with another failing
+    op. ``control`` runs the reference with its failing-op guarantee
+    broken in the program's place (``skip_failing_op``)."""
     verdict = op = 0
     for h, (valid, key) in pairs:
         if control:
-            r = reference.check(h, skip_failing_op=True)
+            r = fam.check(h, skip_failing_op=True)
             valid, key = r["valid"], r["op"]
-        ref = reference.check(h)
+        ref = fam.check(h)
         if valid is not ref["valid"]:
             verdict += 1
         elif ref["valid"] is False and key != ref["op"]:

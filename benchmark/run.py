@@ -3,8 +3,10 @@
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything is found by name: the cell in ``BENCHMARK.json``, its
-configuration in ``benchmark/configs/<config>.json``, its traffic mix
-in ``benchmark/traffic/<traffic>.json`` (whose ``entry`` names one of
+configuration in ``benchmark/configs/<config>.json`` (whose
+``family``, ``cas`` when it names none, is the kind of history it
+checks: ``benchmark/families/<family>.py``), its traffic mix in
+``benchmark/traffic/<traffic>.json`` (whose ``entry`` names one of
 the drivers in ``drivers.py``), each metric's reader in
 ``benchmark/metrics/<metric>.py`` and the peaks in
 ``benchmark/peaks.json``. With ``--trace 0`` the result carries the
@@ -291,7 +293,7 @@ def run_cell(args, bench: dict, allow_cpu: bool = False,
     if hasattr(driver, "backlog"):
         print(f"benchmark: backlog {json.dumps(driver.backlog())}",
               file=log)
-    numbers = drivers.compare(driver.checks(),
+    numbers = drivers.compare(driver.fam, driver.checks(),
                               control=bool(getattr(args, "control", 0)))
     compared = {k: {"value": v, "limit": LIMITS[k]}
                 for k, v in numbers.items()}

@@ -1,6 +1,10 @@
 """Drive a cell on the CPU at a small size: the harness's look for a
 chip skipped, the configuration and the mix shrunk, everything else as
-in a run. For the tests under ``benchmark/tests`` (not tier-1)."""
+in a run. For the tests under ``benchmark/tests`` (not tier-1).
+
+A file is shrunk by its entry in :data:`SMALL`, then by the ``"small"``
+object it carries itself, so a configuration or a traffic mix that a
+later change adds brings its own CPU sizes."""
 from __future__ import annotations
 
 import io
@@ -25,14 +29,18 @@ SMALL = {
 
 
 def run_small(cell: str, seconds: float = 1.0, seed: int = 2**31 + 7,
-              trace: int = 0, control: int = 0, chips=None) -> dict:
-    bench = run.load_json("BENCHMARK.json")
+              trace: int = 0, control: int = 0, chips=None,
+              bench=None) -> dict:
+    """One run of ``cell`` at its small size; ``bench`` in place of
+    ``BENCHMARK.json`` (a copy with cells added, say)."""
+    bench = run.load_json("BENCHMARK.json") if bench is None else bench
     real = run.load_json
 
     def shrunk(*parts):
         d = real(*parts)
         name = os.path.splitext(os.path.basename(parts[-1]))[0]
         d.update(SMALL.get(name, {}))
+        d.update(d.pop("small", {}))
         return d
 
     if chips is not None:
