@@ -142,9 +142,14 @@ class DispatchState:
 
     def place(self, gi: int, g, prep) -> Tuple[int, Dict[str, Any]]:
         """Pin group ``gi`` to its round-robin device; returns the
-        device index and the dispatch span args."""
+        device index and the dispatch span args (with the group's mask
+        width ``W``, kernel lanes ``H`` and config rows ``M``)."""
         di = gi % self.n_dev
         sp: Dict[str, Any] = {"lanes": len(g)}
+        geom = getattr(prep, "geom", None)
+        if geom is not None:
+            _B, W, M, _S, H, _O1, _R_pad = geom
+            sp.update(W=W, H=H, M=M)
         if self.devs:
             prep.device = self.devs[di]
             self.dev_groups[di] += 1
@@ -155,7 +160,8 @@ class DispatchState:
         """Group diag (with pad-lane dedup) + in-flight append."""
         from jepsen_tpu.checkers import reach_batch
 
-        gd = reach_batch.group_diag(fl.geom, fl.R_lens)
+        gd = reach_batch.group_diag(fl.geom, fl.R_lens,
+                                    getattr(fl, "key_W", None))
         x = fl.dsegs.get("xfer")
         if x is not None:
             # wire bytes this group actually moved vs the blanket

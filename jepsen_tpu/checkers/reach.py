@@ -1735,7 +1735,7 @@ class _UnionPrepA:
     per-group native packing (:func:`_union_pack_group`) consumes.
     Pure host data; safe to share with the streaming prep thread."""
     __slots__ = ("memo_u", "S_pad", "noop_op", "opids", "invs", "rets",
-                 "crs", "_P", "_cats", "pack_s")
+                 "crs", "key_W", "_P", "_cats", "pack_s")
 
     def __init__(self, memo_u, S_pad, noop_op, opids, invs, rets, crs):
         self.memo_u = memo_u
@@ -1745,6 +1745,10 @@ class _UnionPrepA:
         self.invs = invs
         self.rets = rets
         self.crs = crs
+        # each live key's mask width, known before any packing (the
+        # planner keeps keys of different mask cost apart)
+        self.key_W = _key_widths(
+            invs, rets, crs, [c & noop_op[o] for c, o in zip(crs, opids)])
         self._P = None
         self._cats = None
         # cumulative stage-B wall (native packing) over this batch —
@@ -1780,6 +1784,48 @@ class _UnionPrepA:
         return np.array(
             [int((self.crs[j] & self.noop_op[self.opids[j]]).sum())
              for j in range(len(self.opids))], np.int64)
+
+
+# entries per chunk of :func:`_key_widths` (bounds its bincount)
+_WIDTH_CHUNK = 1 << 20
+
+
+def _key_widths(invs, rets, crs, drops) -> np.ndarray:
+    """Most ops pending at once in each key: the mask width W that
+    ``preproc_native.build_keyed`` gives it (an op pending from its
+    invocation to its return, a crashed op to the end, a crashed no-op
+    in ``drops`` left out), from the event ranks alone. Keys go in
+    chunks of about ``_WIDTH_CHUNK`` entries: per chunk one bincount
+    of +1 at invocations and -1 at returns over the keys' rank spans
+    laid end to end, a cumsum, and a max per key."""
+    K = len(invs)
+    out = np.zeros(K, np.int32)
+    lo = 0
+    while lo < K:
+        hi, n = lo, 0
+        while hi < K and (hi == lo or n < _WIDTH_CHUNK):
+            n += len(invs[hi])
+            hi += 1
+        lens = np.array([len(x) for x in invs[lo:hi]], np.int64)
+        inv = np.concatenate(invs[lo:hi]).astype(np.int64)
+        ret = np.concatenate(rets[lo:hi]).astype(np.int64)
+        cr = np.concatenate(crs[lo:hi]).astype(bool)
+        keep = ~np.concatenate(drops[lo:hi]).astype(bool)
+        starts = np.concatenate([[0], np.cumsum(lens)[:-1]])
+        # a crashed op's return rank is the history's infinity: its
+        # span ends at its invocation
+        span = np.maximum.reduceat(np.maximum(inv, np.where(cr, inv, ret)),
+                                   starts) + 1
+        base = np.concatenate([[0], np.cumsum(span)[:-1]])
+        kb = np.repeat(base, lens)
+        total = int(span.sum())
+        d = (np.bincount((kb + inv)[keep], minlength=total)
+             - np.bincount((kb + ret)[keep & ~cr], minlength=total))
+        cum = np.cumsum(d)
+        before = np.concatenate([[0], cum[base[1:] - 1]])
+        out[lo:hi] = np.maximum.reduceat(cum, base) - before
+        lo = hi
+    return out
 
 
 def _union_stage_a(model: Model,
@@ -1863,6 +1909,33 @@ def _union_pack_group(sa: "_UnionPrepA", sel: Sequence[int],
     return ret_flat, ops_flat, key_W, key_R, offsets, W
 
 
+def _lockstep_fits(S_pad: int, W: int, M: int, n_ops: int) -> bool:
+    """The Pallas gates of a union batch or lockstep group: the VMEM
+    budget of the transition rows, and a lockstep geometry that can be
+    built at this mask width (:func:`reach_batch.lockstep_geometry`)."""
+    from jepsen_tpu.checkers import reach_batch
+    return (_pallas_fits(S_pad, M, n_ops)
+            and reach_batch.lockstep_geometry(W, S_pad) is not None)
+
+
+def _group_streams(ret_flat, ops_flat, offsets, g, W: int):
+    """Group ``g``'s return streams (live-key positions into the flat
+    union build) at mask width ``W`` (its planned width,
+    :func:`reach_batch.group_widths`): ``(ret_slots, slot_ops, M)``."""
+    return ([ret_flat[offsets[k]:offsets[k + 1]] for k in g],
+            [ops_flat[offsets[k]:offsets[k + 1], :W] for k in g],
+            1 << W)
+
+
+def _plan_union_groups(u, group: int) -> List[List[int]]:
+    """Lockstep groups of a full :func:`_union_prep` tuple, planned by
+    length and by each key's mask width."""
+    from jepsen_tpu.checkers import reach_batch
+    S_pad, W, key_W, key_R = u[1], u[3], u[7], u[8]
+    return reach_batch.plan_buckets([int(r) for r in key_R], W,
+                                    group=group, key_W=key_W, S=S_pad)
+
+
 def _union_prep(model: Model, packed_list: Sequence[h.PackedHistory],
                 live: Sequence[int], max_states: int, max_slots: int,
                 need_pallas: bool = True,
@@ -1893,8 +1966,8 @@ def _union_prep(model: Model, packed_list: Sequence[h.PackedHistory],
     M = 1 << W
     memo_u, S_pad, noop_op = sa.memo_u, sa.S_pad, sa.noop_op
     if not (_fast_ok(S_pad, W, M, memo_u.n_ops)
-            and (not need_pallas
-                 or _pallas_fits(S_pad, M, memo_u.n_ops))):
+            and (not need_pallas or _lockstep_fits(S_pad, W, M,
+                                                   memo_u.n_ops))):
         return None                     # general path may still fit
     _inv_c, _ret_c, opid_cat, crs_cat, offs = sa.cats()
     P = sa.P()
@@ -2024,17 +2097,15 @@ def check_batch(model: Model, packed_list: Sequence[h.PackedHistory], *,
         return results  # type: ignore[return-value]
     (memo_u, S_pad, P, W, M, ret_flat, ops_flat, key_W, key_R,
      offsets, opid_cat, crs_cat, offs, noop_op) = u
-    from jepsen_tpu.checkers import reach_batch
     try:
         # length-bucketed lane packing + pipelined group dispatch: a
         # ragged batch no longer pads every history to the longest,
         # and group g+1's marshalling/compile hides under group g's
         # device walk
-        groups = reach_batch.plan_buckets(
-            [int(r) for r in key_R], W, group=group)
+        groups = _plan_union_groups(u, group)
         dead = _dispatch_lockstep_groups(
-            P, ret_flat, ops_flat, offsets, groups, M, len(live), diag,
-            prep_base_s=sa.pack_s if sa is not None else 0.0)
+            P, ret_flat, ops_flat, offsets, groups, key_W, len(live),
+            diag, prep_base_s=sa.pack_s if sa is not None else 0.0)
     except Exception as e:                              # noqa: BLE001
         _warn_pallas_failed(repr(e))
         obs.engine_fallback("reach-lockstep", type(e).__name__,
@@ -2314,7 +2385,7 @@ _LockstepDispatchState = dispatch_core.DispatchState
 
 
 def _dispatch_lockstep_groups(P, ret_flat, ops_flat, offsets, groups,
-                              M: int, n_live: int,
+                              key_W, n_live: int,
                               diag: Optional[dict] = None,
                               prep_base_s: float = 0.0,
                               devices: Optional[Sequence] = None,
@@ -2336,6 +2407,8 @@ def _dispatch_lockstep_groups(P, ret_flat, ops_flat, offsets, groups,
     while the rest keep walking. Fills ``diag`` (when given) with
     per-group geometry, pack efficiency (real vs padded returns),
     kernel-cache counters, and the prep/dispatch/fetch wall breakdown.
+    Each group walks at its planned mask width
+    (:func:`reach_batch.group_widths` of the live keys' ``key_W``).
     Returns the per-live-key local dead indices."""
     from jepsen_tpu.checkers import reach_batch
 
@@ -2347,14 +2420,14 @@ def _dispatch_lockstep_groups(P, ret_flat, ops_flat, offsets, groups,
     prep_s = prep_base_s
     dispatch_s = 0.0
     gdiags: List[dict] = []
+    widths = reach_batch.group_widths(groups, key_W, P.shape[1])
     for gi, g in enumerate(groups):
         t0 = _time.monotonic()
         with obs.span("lockstep.prep", lanes=len(g)):
+            rets, ops, M = _group_streams(ret_flat, ops_flat, offsets,
+                                          g, widths[gi])
             prep = reach_batch.prepare_returns_batch(
-                P,
-                [ret_flat[offsets[k]:offsets[k + 1]] for k in g],
-                [ops_flat[offsets[k]:offsets[k + 1]] for k in g],
-                M)
+                P, rets, ops, M, key_W=[key_W[k] for k in g])
         t1 = _time.monotonic()
         prep_s += t1 - t0
         gdiags.append(st.stage(gi, g, prep,
@@ -2381,7 +2454,7 @@ def _stream_prep_enabled() -> bool:
     return not os.environ.get("JEPSEN_TPU_NO_STREAM_PREP")
 
 
-def _dispatch_lockstep_stream(sa: "_UnionPrepA", groups,
+def _dispatch_lockstep_stream(sa: "_UnionPrepA", groups, widths,
                               max_slots: int, n_live: int,
                               diag: Optional[dict],
                               devices: Optional[Sequence] = None,
@@ -2456,9 +2529,14 @@ def _dispatch_lockstep_stream(sa: "_UnionPrepA", groups,
                     _put(("decline", gi, None))
                     return
                 ret_flat, ops_flat, key_W, key_R, offsets, W = built
+                if widths[gi] > W:      # the planned width of its class
+                    ops_flat = np.pad(ops_flat, ((0, 0), (0, widths[gi] - W)),
+                                      constant_values=-1)
+                    W = widths[gi]
                 M = 1 << W
                 if not (_fast_ok(sa.S_pad, W, M, sa.memo_u.n_ops)
-                        and _pallas_fits(sa.S_pad, M, sa.memo_u.n_ops)):
+                        and _lockstep_fits(sa.S_pad, W, M,
+                                           sa.memo_u.n_ops)):
                     _put(("decline", gi, None))
                     return
                 prep = reach_batch.prepare_returns_batch(
@@ -2467,7 +2545,7 @@ def _dispatch_lockstep_stream(sa: "_UnionPrepA", groups,
                      for k in range(len(g))],
                     [ops_flat[offsets[k]:offsets[k + 1]]
                      for k in range(len(g))],
-                    M)
+                    M, key_W=key_W)
                 prep_wall[0] += _time.monotonic() - t0
                 if not _put(("group", gi, (prep, key_W, key_R))):
                     return
@@ -2577,8 +2655,10 @@ def _check_lockstep_stream(engine: str, model: Model,
     lens = [int(packed_list[i].n_ok) for i in live]
     # the planner's floor only needs a width HINT (a coarser floor
     # splits small keys into more groups — suboptimal packing, never
-    # incorrect); the true union W is only known after native packing
-    groups = reach_batch.plan_buckets(lens, max_slots, group=group)
+    # incorrect); stage A's per-key widths keep keys of different mask
+    # cost in different groups
+    groups = reach_batch.plan_buckets(lens, max_slots, group=group,
+                                      key_W=sa.key_W, S=sa.S_pad)
     pad_lanes = 0
     if devices is not None and len(devices) > 1:
         groups, pad_lanes = reach_batch.shard_groups_for_mesh(
@@ -2586,9 +2666,11 @@ def _check_lockstep_stream(engine: str, model: Model,
     if len(groups) < 2:
         return None         # nothing to hide — synchronous is simpler
     try:
-        r = _dispatch_lockstep_stream(sa, groups, max_slots, len(live),
-                                      diag, devices=devices,
-                                      pad_lanes=pad_lanes)
+        r = _dispatch_lockstep_stream(
+            sa, groups,
+            reach_batch.group_widths(groups, sa.key_W, sa.S_pad),
+            max_slots, len(live), diag, devices=devices,
+            pad_lanes=pad_lanes)
     except Exception as e:                              # noqa: BLE001
         # dispatch-side failure: recorded, then the synchronous path
         # gets its chance (and takes the existing per-history
@@ -2654,15 +2736,14 @@ def _check_many_lockstep(model: Model,
                            max_slots, u_box)
     if u is None:
         return None
-    from jepsen_tpu.checkers import reach_batch
-    (_memo_u, _S_pad, P, W, M, ret_flat, ops_flat, _key_W, key_R,
+    (_memo_u, _S_pad, P, _W, _M, ret_flat, ops_flat, key_W, _key_R,
      offsets, _opid_cat, _crs_cat, _offs, _noop_op) = u
-    groups = reach_batch.plan_buckets(
-        [int(r) for r in key_R], W, group=group or _BATCH_GROUP)
+    groups = _plan_union_groups(u, group or _BATCH_GROUP)
     sa_box = (u_box or {}).get("sa")
     try:
         dead = _dispatch_lockstep_groups(
-            P, ret_flat, ops_flat, offsets, groups, M, len(live), diag,
+            P, ret_flat, ops_flat, offsets, groups, key_W, len(live),
+            diag,
             prep_base_s=sa_box.pack_s if sa_box is not None else 0.0)
     except Exception as e:                              # noqa: BLE001
         _warn_pallas_failed(f"lockstep: {e!r}")
@@ -2767,10 +2848,10 @@ def stage_check_many(model: Model,
                            max_slots, None)
     if u is None:
         return None
-    (_memo_u, _S_pad, P, W, M, ret_flat, ops_flat, _key_W, key_R,
+    (_memo_u, _S_pad, P, _W, _M, ret_flat, ops_flat, key_W, _key_R,
      offsets, _opid_cat, _crs_cat, _offs, _noop_op) = u
-    groups = reach_batch.plan_buckets(
-        [int(r) for r in key_R], W, group=group or _BATCH_GROUP)
+    groups = _plan_union_groups(u, group or _BATCH_GROUP)
+    widths = reach_batch.group_widths(groups, key_W, P.shape[1])
     dead = np.full(len(live), -1, np.int64)
     st = _LockstepDispatchState(None, dead)
     gdiags: List[dict] = []
@@ -2779,11 +2860,10 @@ def stage_check_many(model: Model,
         for gi, g in enumerate(groups):
             ta = _time.monotonic()
             with obs.span("lockstep.prep", lanes=len(g)):
+                rets, ops, M = _group_streams(ret_flat, ops_flat,
+                                              offsets, g, widths[gi])
                 prep = reach_batch.prepare_returns_batch(
-                    P,
-                    [ret_flat[offsets[k]:offsets[k + 1]] for k in g],
-                    [ops_flat[offsets[k]:offsets[k + 1]] for k in g],
-                    M)
+                    P, rets, ops, M, key_W=[key_W[k] for k in g])
             tb = _time.monotonic()
             prep_s += tb - ta
             gdiags.append(st.stage(gi, g, prep,
@@ -2936,17 +3016,17 @@ def _check_many_mesh_lockstep(model: Model,
                                max_slots, u_box)
         if u is None:
             return None
-        (_memo_u, _S_pad, P, W, M, ret_flat, ops_flat, _key_W, key_R,
+        (_memo_u, _S_pad, P, _W, _M, ret_flat, ops_flat, key_W, _key_R,
          offsets, _opid_cat, _crs_cat, _offs, _noop_op) = u
-        groups = reach_batch.plan_buckets(
-            [int(r) for r in key_R], W, group=group or _BATCH_GROUP)
+        groups = _plan_union_groups(u, group or _BATCH_GROUP)
         groups, pad_lanes = reach_batch.shard_groups_for_mesh(
             groups, len(devs))
         if len(groups) < 2:
             return None         # unsplittable: nothing to shard
         sa_box = (u_box or {}).get("sa")
         dead = _dispatch_lockstep_groups(
-            P, ret_flat, ops_flat, offsets, groups, M, len(live), diag,
+            P, ret_flat, ops_flat, offsets, groups, key_W, len(live),
+            diag,
             prep_base_s=sa_box.pack_s if sa_box is not None else 0.0,
             devices=devs, pad_lanes=pad_lanes)
     except Exception as e:                              # noqa: BLE001

@@ -47,7 +47,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import os
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -90,14 +90,112 @@ def _adaptive_block(H: int, W: int) -> int:
     return min(_BLOCK, b)
 
 
-def plan_buckets(R_lens, W: int, *, group: int = 32) -> List[List[int]]:
+# Mosaic's default scoped-VMEM limit (v5e), what a kernel that asks
+# for no more gets; and what the wide-M kernel may ask for of the 128
+# MiB of VMEM a v5e core has
+_SCOPED_VMEM = 16 << 20
+_VMEM_CAP = 96 << 20
+# transition rows the VMEM budget allows for (a 5-value CAS register's
+# union alphabet is 37 with the sentinel, a 7-value one 64)
+_O1_BUDGET = 64
+# rows of the config set the wide-M kernel touches per inner step
+_TILE_ROWS = 512
+# f32 temporaries of one tile the wide-M kernel's body keeps live
+_TILE_TEMPS = 8
+
+
+class Geometry(NamedTuple):
+    """Which lockstep kernel a group of one mask width walks, and how
+    many histories it walks together: ``wide`` for the wide-M kernel
+    (``_make_wide_kernel``), else the one-array kernel, at ``H``
+    lanes. The dispatch derives the rest from the group's shape: the
+    block from :func:`group_geom`, the dtype from the lane width, the
+    wide-M kernel's scoped-VMEM limit from :func:`_wide_vmem_limit`."""
+    H: int
+    wide: bool
+
+
+def _pad(x: int, m: int) -> int:
+    return -(-int(x) // m) * m
+
+
+def vmem_bytes(W: int, S: int, H: int, B: int, wide: bool,
+               O1: int = _O1_BUDGET) -> int:
+    """VMEM the batch kernel holds at this geometry, buffer by buffer,
+    each padded to the (8 sublane, 128 lane) tile (a lane axis narrower
+    than 128 still takes 128 lanes).
+
+    Both kernels: the double-buffered ``jv`` block ``(B, HS)`` f32 and
+    transition rows ``(O1, S, S)`` f32, and the ``(2, HS, W*HS)``
+    transition scratch in the compute dtype. The one-array kernel
+    (``_make_batch_kernel``) adds the double-buffered ``(M, HS)`` seed
+    (compute dtype), checkpoint and final (f32) blocks, the ``(M, HS)``
+    config-set scratch, the fire matmul's ``(M, W*HS)`` f32 image and
+    two ``(M, HS)`` f32 blend temporaries. The wide-M kernel keeps the
+    seed, checkpoints and final in HBM and adds only its two ``(M,
+    HS)`` config-set buffers and ``_TILE_TEMPS`` f32 temporaries of one
+    ``_TILE_ROWS``-row tile. Calibrated on AOT compiles for a v5e at
+    S=8: the one-array kernel at H=32 fits the default limit up to W=9
+    and is refused at W=10, at H=16 fits at W=10 and is refused at
+    W=11 (``tests/test_chip_compile.py``)."""
+    M = 1 << W
+    HS = H * S
+    lanes = _pad(HS, 128)
+    c = 2 if (HS >= 128 and _COMPUTE_DTYPE == "bfloat16") else 4
+    common = (2 * _pad(B, 8) * lanes * 4
+              + 2 * O1 * _pad(S, 8) * _pad(S, 128) * 4
+              + 2 * _pad(HS, 16) * _pad(W * HS, 128) * c)
+    if wide:
+        T = min(M, _TILE_ROWS)
+        return (common + 2 * M * lanes * c
+                + _TILE_TEMPS * _pad(T, 8) * lanes * 4)
+    return (common + 2 * M * lanes * c + 4 * M * lanes * 4
+            + M * lanes * c + M * _pad(W * HS, 128) * 4
+            + 2 * M * lanes * 4)
+
+
+def lockstep_geometry(W: int, S: int) -> Optional[Geometry]:
+    """The one place a lockstep group's kernel and lanes are chosen
+    from its mask width ``W`` and state count ``S``: 32 histories (the
+    keyed lanes' group, ``reach._BATCH_GROUP``) on the one-array kernel
+    where, at the SMEM-budgeted block, it fits Mosaic's default scoped
+    VMEM (W <= 9 at S = 8: every group of the fault-free keyed batch);
+    else the wide-M kernel at the narrowest full lane tile (``128 //
+    S`` histories: 16 at S = 8), which asks for a raised scoped limit
+    of twice its counted buffers; None where that limit passes
+    ``_VMEM_CAP`` (no group of that width can be built)."""
+    lanes = 32
+    if vmem_bytes(W, S, lanes, _adaptive_block(lanes, max(W, 1)),
+                  False) <= _SCOPED_VMEM:
+        return Geometry(lanes, False)
+    H = max(1, 128 // S)
+    if _wide_vmem_limit(W, S, H, _adaptive_block(H, max(W, 1))) \
+            > _VMEM_CAP:
+        return None
+    return Geometry(H, True)
+
+
+def _wide_vmem_limit(W: int, S: int, H: int, B: int,
+                     O1: int = _O1_BUDGET) -> int:
+    """The scoped VMEM the wide-M kernel asks for: twice its counted
+    buffers (room for what the compiler adds), never below the
+    default."""
+    return max(_SCOPED_VMEM, 2 * vmem_bytes(W, S, H, B, True, O1))
+
+
+def plan_buckets(R_lens, W: int, *, group: int = 32, key_W=None,
+                 S: int = 8) -> List[List[int]]:
     """Length-bucketed lane packing: partition a ragged batch of return
     streams into lockstep dispatch groups such that no stream walks
     more than ~2x its own padded length. Streams are assigned to
-    power-of-two length buckets and each bucket is greedily chunked
-    (longest first) into groups of at most ``group`` lanes — so a
-    10k-return history no longer forces 200-return co-batched keys to
-    walk 10k padded lockstep steps.
+    power-of-two length buckets and each bucket is split (longest
+    first) into the fewest groups of at most ``group`` lanes, as even
+    as can be — so a 10k-return history no longer forces 200-return
+    co-batched keys to walk 10k padded lockstep steps, and a bucket's
+    groups, sizes within one of each other, pad to one lane width and
+    so share one compiled geometry (a short tail group would compile
+    its own, and its own rescue walk only in the batch whose tail holds
+    a dying key).
 
     Lengths at or below the dispatch block size (the SMEM-budgeted
     ``_adaptive_block`` floor, which every group pads to anyway) share
@@ -107,23 +205,59 @@ def plan_buckets(R_lens, W: int, *, group: int = 32) -> List[List[int]]:
     the floor bucket merely coarser there — suboptimal packing, never
     incorrect.) Groups are ordered longest bucket first so the
     pipelined scheduler overlaps later (cheaper) groups' marshalling
-    and compiles with the big walk. Returns a partition of
-    ``range(len(R_lens))`` — every index appears in exactly one
-    group."""
-    floor = _adaptive_block(
-        max(1, min(group, len(R_lens))), max(W, 1))
-    order = sorted(range(len(R_lens)),
-                   key=lambda i: (-int(R_lens[i]), i))
-    buckets: dict = {}
-    for i in order:
-        eff = max(int(R_lens[i]), floor, 1)
-        buckets.setdefault((eff - 1).bit_length(), []).append(i)
+    and compiles with the big walk.
+
+    With per-key mask widths ``key_W`` (and the batch's state count
+    ``S``) keys are first split by mask cost: every key whose width
+    fits the one-array kernel (:func:`lockstep_geometry`) shares one
+    class, planned as above with the width hint ``W``; each wider
+    width is a class of its own, in groups of its geometry's lanes, so
+    a key with crashed ops never sets the mask width of its crash-free
+    neighbours. Wide classes come first, widest first (the costliest
+    walks). Returns a partition of ``range(len(R_lens))`` — every
+    index appears in exactly one group."""
+    widths = [0] * len(R_lens) if key_W is None else \
+        [int(w) for w in key_W]
+    geos = {w: lockstep_geometry(w, S) for w in set(widths) if w}
+    classes: dict = {}
+    for i, w in enumerate(widths):
+        wide = w and geos[w] is not None and geos[w].wide
+        classes.setdefault(w if wide else 0, []).append(i)
     groups: List[List[int]] = []
-    for key in sorted(buckets, reverse=True):
-        idxs = buckets[key]
-        for j in range(0, len(idxs), group):
-            groups.append(idxs[j:j + group])
+    for c in sorted(classes, reverse=True):
+        idxs = classes[c]
+        size = geos[c].H if c else group
+        floor = _adaptive_block(max(1, min(size, len(idxs))),
+                                max(c or W, 1))
+        order = sorted(idxs, key=lambda i: (-int(R_lens[i]), i))
+        buckets: dict = {}
+        for i in order:
+            eff = max(int(R_lens[i]), floor, 1)
+            buckets.setdefault((eff - 1).bit_length(), []).append(i)
+        for key in sorted(buckets, reverse=True):
+            b = buckets[key]
+            k = -(-len(b) // size)
+            groups.extend(b[j * len(b) // k:(j + 1) * len(b) // k]
+                          for j in range(k))
     return groups
+
+
+def group_widths(groups, key_W, S: int) -> List[int]:
+    """The mask width each planned group walks at: a wide class's own
+    W, and for the class the one-array kernel holds (see
+    :func:`plan_buckets`) the widest W of any key in that class — so
+    its groups share one geometry whichever keys a rotated batch puts
+    together, and no batch compiles a geometry the one before it did
+    not."""
+    geos = {int(w): lockstep_geometry(int(w), S) for w in set(key_W)}
+    narrow = [int(w) for w in key_W
+              if geos[int(w)] is not None and not geos[int(w)].wide]
+    top = max(narrow, default=1)
+    out = []
+    for g in groups:
+        w = max(int(key_W[k]) for k in g)
+        out.append(top if w <= top else w)
+    return out
 
 
 def mesh_lockstep_enabled() -> bool:
@@ -177,14 +311,23 @@ def group_geom(R_max: int, H: int, W: int, *,
     return B, R_pad
 
 
-def group_diag(geom, R_lens) -> dict:
+def group_diag(geom, R_lens, key_W=None) -> dict:
     """Per-group geometry + pack-efficiency accounting for one lockstep
     dispatch (bench.py's batch rung): real vs padded returns under this
-    group's ``(H, B, W, S, M, R_pad)`` geometry."""
+    group's ``(H, B, W, S, M, R_pad)`` geometry; the config rows
+    walked (``rows`` = H * M * R_pad); and, over the real lanes' own
+    returns, the rows at the group's mask width (``mask_rows`` = M *
+    sum of R_key) against those at each key's own (``own_rows`` = sum
+    of 2**W_key * R_key over ``key_W``, the lanes' mask widths; the
+    group's W where unknown)."""
     B, W, M, S, H, O1, R_pad = geom
     real = int(sum(int(r) for r in R_lens))
-    return {"H": H, "B": B, "W": W, "S": S, "R_pad": R_pad,
-            "real_returns": real, "padded_returns": H * R_pad}
+    kw = [W] * len(R_lens) if key_W is None else key_W
+    own = sum((1 << int(w)) * int(r) for w, r in zip(kw, R_lens))
+    return {"H": H, "B": B, "W": W, "M": M, "S": S, "R_pad": R_pad,
+            "real_returns": real, "padded_returns": H * R_pad,
+            "rows": H * M * R_pad, "mask_rows": M * real,
+            "own_rows": own}
 
 
 def kernel_cache_info() -> dict:
@@ -319,6 +462,151 @@ def _make_batch_kernel(B: int, W: int, M: int, S: int, H: int,
     return kernel
 
 
+def _make_wide_kernel(B: int, W: int, M: int, S: int, H: int, O1: int,
+                      n_blocks: int, n_pass: int, T: int):
+    """The batch walk for a config set too large for the one-array
+    kernel's VMEM (``vmem_bytes``): the same per-return math, with the
+    ``[M, HS]`` set in two VMEM buffers walked ``T`` rows at a time.
+    The seed, the block checkpoints and the final set stay in HBM and
+    cross by DMA, in the compute dtype.
+
+    A fire pass updates the set in place, slot by slot: slot ``jj``
+    ORs the transition image of every mask with bit ``jj`` clear into
+    the mask ``2**jj`` rows further on (within a tile below ``T``
+    rows, between tiles above). A slot fired later in the pass sees
+    what earlier slots added, so a pass reaches at least the one-array
+    kernel's Jacobi pass and never past the closure: with ``n_pass``
+    at least the pending count both walks end on the same closure, and
+    a capped ladder stays sound for "valid", as there. The projection
+    writes the other buffer. Passes loop ``min(pendmax, n_pass)``
+    times, and a step at which no lane has an op pending (a padded
+    return) is skipped, its transition tiles never gathered."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    HS = H * S
+    n_tiles = M // T
+    n_pairs = M // (2 * T)
+
+    def rows(start):
+        return pl.ds(pl.multiple_of(start, T), T)
+
+    def pair_start(i, b: int):
+        """Start row of the ``i``-th tile whose masks have bit ``b``
+        clear (``b`` a multiple of ``T``)."""
+        nb = b // T
+        return ((i // nb) * 2 * nb + i % nb) * T
+
+    def kernel(slot_ops_ref, pendmax_ref, jv_ref, P_ref, R0_hbm,
+               ckpt_hbm, final_hbm, R_scr, G_scr, cur_ref):
+        step = pl.program_id(0)
+        cdt = R_scr.dtype
+
+        @pl.when(step == 0)
+        def _init():
+            pltpu.sync_copy(R0_hbm, R_scr.at[0])
+            cur_ref[0] = 0
+            G_scr[:] = jnp.zeros_like(G_scr)
+
+        pltpu.sync_copy(R_scr.at[cur_ref[0]], ckpt_hbm.at[step])
+        _gather_G_b(slot_ops_ref, P_ref, 0, W, H, S, O1, G_scr, 0)
+
+        def fire(c, g):
+            for jj in range(W):
+                b = 1 << jj
+                Gj = G_scr[g, :, jj * HS:(jj + 1) * HS]
+                if b >= T:
+                    def across(i, _, b=b, Gj=Gj):
+                        lo = pair_start(i, b)
+                        img = jnp.dot(R_scr[c, rows(lo), :], Gj,
+                                      preferred_element_type=jnp.float32)
+                        hi = R_scr[c, rows(lo + b), :]
+                        R_scr[c, rows(lo + b), :] = jnp.maximum(
+                            hi, (img > 0.5).astype(cdt))
+                        return 0
+                    jax.lax.fori_loop(0, n_pairs, across, 0)
+                else:
+                    def within(t, _, b=b, Gj=Gj):
+                        x = R_scr[c, rows(t * T), :]
+                        f = jnp.dot(x, Gj,
+                                    preferred_element_type=jnp.float32)
+                        half = T // (2 * b)
+                        xr = x.reshape(half, 2, b, HS)
+                        fr = f.reshape(half, 2, b, HS)
+                        hi = jnp.maximum(xr[:, 1],
+                                         (fr[:, 0] > 0.5).astype(cdt))
+                        R_scr[c, rows(t * T), :] = jnp.stack(
+                            [xr[:, 0], hi], axis=1).reshape(T, HS)
+                        return 0
+                    jax.lax.fori_loop(0, n_tiles, within, 0)
+
+        def project(c, d, row):
+            # as the one-array kernel: lane block h keeps the masks
+            # that fired its returning slot, bit cleared (-1: identity)
+            none = (row < 0).astype(cdt)
+            ind = [(row == jj).astype(cdt) for jj in range(W)]
+
+            def within(t, _):
+                x = R_scr[c, rows(t * T), :]
+                acc = x * none
+                for jj in range(W):
+                    b = 1 << jj
+                    if b >= T:
+                        break
+                    taken = x.reshape(T // (2 * b), 2, b, HS)[:, 1]
+                    p = jnp.stack([taken, jnp.zeros_like(taken)],
+                                  axis=1).reshape(T, HS)
+                    acc = acc + p * ind[jj]
+                R_scr[d, rows(t * T), :] = acc
+                return 0
+            jax.lax.fori_loop(0, n_tiles, within, 0)
+            for jj in range(W):
+                b = 1 << jj
+                if b < T:
+                    continue
+
+                def across(i, _, b=b, on=ind[jj]):
+                    lo = pair_start(i, b)
+                    R_scr[d, rows(lo), :] = (R_scr[d, rows(lo), :]
+                                             + R_scr[c, rows(lo + b), :]
+                                             * on)
+                    return 0
+                jax.lax.fori_loop(0, n_pairs, across, 0)
+
+        def do_return(k, _):
+            g = k % 2
+            kn = jnp.minimum(k + 1, B - 1)
+
+            @pl.when(pendmax_ref[kn] > 0)
+            def _prefetch():
+                _gather_G_b(slot_ops_ref, P_ref, kn, W, H, S, O1, G_scr,
+                            (k + 1) % 2)
+            pend = pendmax_ref[k]
+
+            @pl.when(pend > 0)
+            def _walk():
+                c = cur_ref[0]
+
+                def one_pass(_p, carry):
+                    fire(c, g)
+                    return carry
+                jax.lax.fori_loop(0, jnp.minimum(pend, n_pass), one_pass,
+                                  0)
+                project(c, 1 - c, jv_ref[k])
+                cur_ref[0] = 1 - c
+            return 0
+
+        jax.lax.fori_loop(0, B, do_return, 0)
+
+        @pl.when(step == n_blocks - 1)
+        def _finish():
+            pltpu.sync_copy(R_scr.at[cur_ref[0]], final_hbm)
+
+    return kernel
+
+
 # compute dtype for the config sets and transition operand. bf16 is
 # EXACT here because every stored value is 0 or 1 (exactly
 # representable) and the fire dot ACCUMULATES IN F32 via
@@ -328,15 +616,19 @@ def _make_batch_kernel(B: int, W: int, M: int, S: int, H: int,
 # before anything is rounded back to bf16. Halves the VMEM footprint
 # and traffic of the G operand scratch — the resource that pinned the
 # lockstep width at 32 (H=64's f32 geometry exceeded the 16 MB
-# scoped-VMEM limit by 212 KB). Checkpoint/final outputs stay f32 so
-# host-side localization is unchanged.
+# scoped-VMEM limit by 212 KB). The one-array kernel's checkpoint/final
+# outputs stay f32; the wide-M kernel's cross in the compute dtype, and
+# the host reads both as f32.
 _COMPUTE_DTYPE = "bfloat16"
 
 
 @functools.cache
 def _batch_call(B: int, W: int, M: int, S: int, H: int, O1: int,
                 R_pad: int, n_pass: int, interpret: bool, dtype: str,
-                donate: bool = False):
+                donate: bool = False, tile: int = 0):
+    """The jitted batch walk at one geometry: the one-array kernel, or
+    with ``tile`` rows (> 0) the wide-M kernel, which asks for the
+    scoped VMEM its buffers need (:func:`_wide_vmem_limit`)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -357,37 +649,54 @@ def _batch_call(B: int, W: int, M: int, S: int, H: int, O1: int,
     PB = max(B, 1024)
     SOW = B * H * W
     SOW_P = -(-SOW // 1024) * 1024
-    kernel = _make_batch_kernel(B, W, M, S, H, O1, n_blocks, n_pass)
-    call = pl.pallas_call(
-        kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((SOW_P,), lambda i: (i,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((PB,), lambda i: (i,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((B, HS), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((O1, S, S), lambda i: (0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((M, HS), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, M, HS), lambda i: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((M, HS), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_blocks, M, HS), jnp.float32),
-            jax.ShapeDtypeStruct((M, HS), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((M, HS), cdt),
-            pltpu.VMEM((2, HS, W * HS), cdt),
-        ],
-        interpret=interpret,
-    )
+    scalars = [
+        pl.BlockSpec((SOW_P,), lambda i: (i,), memory_space=pltpu.SMEM),
+        pl.BlockSpec((PB,), lambda i: (i,), memory_space=pltpu.SMEM),
+        pl.BlockSpec((B, HS), lambda i: (i, 0), memory_space=pltpu.VMEM),
+        pl.BlockSpec((O1, S, S), lambda i: (0, 0, 0),
+                     memory_space=pltpu.VMEM),
+    ]
+    if tile:
+        hbm = pl.BlockSpec(memory_space=pl.ANY)
+        call = pl.pallas_call(
+            _make_wide_kernel(B, W, M, S, H, O1, n_blocks, n_pass, tile),
+            grid=(n_blocks,),
+            in_specs=scalars + [hbm],
+            out_specs=[hbm, hbm],
+            out_shape=[jax.ShapeDtypeStruct((n_blocks, M, HS), cdt),
+                       jax.ShapeDtypeStruct((M, HS), cdt)],
+            scratch_shapes=[pltpu.VMEM((2, M, HS), cdt),
+                            pltpu.VMEM((2, HS, W * HS), cdt),
+                            pltpu.SMEM((1,), jnp.int32)],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=min(_VMEM_CAP,
+                                     _wide_vmem_limit(W, S, H, B, O1))),
+            interpret=interpret,
+        )
+    else:
+        call = pl.pallas_call(
+            _make_batch_kernel(B, W, M, S, H, O1, n_blocks, n_pass),
+            grid=(n_blocks,),
+            in_specs=scalars + [
+                pl.BlockSpec((M, HS), lambda i: (0, 0),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, M, HS), lambda i: (i, 0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((M, HS), lambda i: (0, 0),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((n_blocks, M, HS), jnp.float32),
+                jax.ShapeDtypeStruct((M, HS), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((M, HS), cdt),
+                pltpu.VMEM((2, HS, W * HS), cdt),
+            ],
+            interpret=interpret,
+        )
 
     def run(slot_ops, ret_slot_rh, P, R0):
         # device-side derivations (the wire carries only narrow ints
@@ -428,6 +737,26 @@ def _batch_call(B: int, W: int, M: int, S: int, H: int, O1: int,
     return jax.jit(run, donate_argnums=(3,)) if donate else jax.jit(run)
 
 
+def _wide_tile(W: int, M: int, S: int) -> int:
+    """Rows per tile of the wide-M kernel for a group of mask width
+    ``W`` (and ``M`` config rows) at ``S`` states, or 0 where the
+    group walks the one-array kernel (:func:`lockstep_geometry`; also
+    where no kernel fits, as the host gates never send such a group:
+    a direct caller's compile is then refused and takes its
+    fallback)."""
+    geo = lockstep_geometry(W, S)
+    return min(M, _TILE_ROWS) if geo is not None and geo.wide else 0
+
+
+def _first_passes(W: int, M: int, S: int) -> int:
+    """Fire passes of a group's first walk: on the wide-M kernel, whose
+    passes loop at run time, every pass a return can need (exact, so a
+    key's death needs no rescue walk); on the one-array kernel, whose
+    passes unroll, the capped ladder (``_FAST_PASSES``), with an exact
+    rescue on a death."""
+    return W if _wide_tile(W, M, S) else min(W, _FAST_PASSES)
+
+
 def pack_batch_operands(P: np.ndarray, ret_slots: List[np.ndarray],
                         slot_ops: List[np.ndarray], M: int, *,
                         interpret: bool = False):
@@ -437,13 +766,20 @@ def pack_batch_operands(P: np.ndarray, ret_slots: List[np.ndarray],
     ``ret_slot_rh[r, h]`` — so one SMEM/VMEM block holds a contiguous
     run of lockstep steps. Returns ``(geom, host_args, R_lens)``."""
     O1, S, _ = P.shape
-    H = len(ret_slots)
+    lanes = len(ret_slots)
     W = max(int(so.shape[1]) for so in slot_ops)
+    # the wide-M kernel, and the bf16 one-array kernel (whose mask
+    # reshapes Mosaic refuses on a lane axis of 28 x 8 = 224), walk
+    # whole 128-lane tiles: a group short of one gets pad lanes
+    # (identity returns, so each stays alive and is never read)
+    H = lanes
+    if _wide_tile(W, M, S) or (lanes * S > 128 and lanes * S % 128):
+        H = _pad(lanes, max(1, 128 // S))
     R_max = max(1, max(int(r.shape[0]) for r in ret_slots))
     B, R_pad = group_geom(R_max, H, W, interpret=interpret)
     rs_rh = np.full((R_pad, H), -1, np.int8)
     ops_rhw = np.full((R_pad, H, W), -1, np.int32)
-    for h in range(H):
+    for h in range(lanes):
         n = int(ret_slots[h].shape[0])
         rs_rh[:n, h] = ret_slots[h]
         ops_rhw[:n, h, :slot_ops[h].shape[1]] = slot_ops[h]
@@ -489,7 +825,9 @@ def _pipe_walk_b(host_args, geom, n_pass: int, interpret: bool,
     # HS=64 runs ~2.0 s in bf16 vs 0.47 s in f32, while HS ≥ 128
     # geometries are 6-8% FASTER in bf16)
     cdt = _COMPUTE_DTYPE if HS >= 128 else "float32"
-    run = _batch_call(B, W, M, S, H, O1, seg, n_pass, interpret, cdt)
+    tile = _wide_tile(W, M, S)
+    run = _batch_call(B, W, M, S, H, O1, seg, n_pass, interpret, cdt,
+                      False, tile)
     run_d = None
     donate = transfer.donate_enabled()
     sextet = transfer.packed_enabled() and transfer.sextet_ok(O1)
@@ -571,7 +909,7 @@ def _pipe_walk_b(host_args, geom, n_pass: int, interpret: bool,
             if use_donate:
                 if run_d is None:
                     run_d = _batch_call(B, W, M, S, H, O1, seg, n_pass,
-                                        interpret, cdt, True)
+                                        interpret, cdt, True, tile)
                 ck, R_cur = run_d(a, b, dsegs["dP"], R_cur)
                 obs.count("donate.reuse")
             else:
@@ -670,14 +1008,15 @@ class BatchInflight:
     walk carries its queued device results in ``word_out``."""
     __slots__ = ("P", "geom", "host_args", "R_lens", "dsegs",
                  "ckpts", "final", "interpret", "device", "degraded",
-                 "body", "word_out")
+                 "body", "word_out", "key_W")
 
     def __init__(self, P, geom, host_args, R_lens, dsegs, ckpts,
-                 final, interpret, device=None):
+                 final, interpret, device=None, key_W=None):
         self.P = P
         self.geom = geom
         self.host_args = host_args
         self.R_lens = R_lens
+        self.key_W = key_W
         self.dsegs = dsegs
         self.ckpts = ckpts
         self.final = final
@@ -700,16 +1039,18 @@ class BatchPrepared:
     A mesh scheduler sets ``device`` before dispatching to pin this
     group's lane block to one chip (None = jax's default device).
     ``body`` (None = resolve at dispatch: autotune winner, force
-    gate, else dense) selects the kernel body this group walks."""
+    gate, else dense) selects the kernel body this group walks.
+    ``key_W`` holds each lane's own mask width (for ``group_diag``)."""
     __slots__ = ("P", "geom", "host_args", "R_lens", "interpret",
-                 "device", "body")
+                 "device", "body", "key_W")
 
     def __init__(self, P, geom, host_args, R_lens, interpret,
-                 device=None, body=None):
+                 device=None, body=None, key_W=None):
         self.P = P
         self.geom = geom
         self.host_args = host_args
         self.R_lens = R_lens
+        self.key_W = key_W
         self.interpret = interpret
         self.device = device
         self.body = body
@@ -717,15 +1058,18 @@ class BatchPrepared:
 
 def prepare_returns_batch(P: np.ndarray, ret_slots: List[np.ndarray],
                           slot_ops: List[np.ndarray], M: int, *,
-                          interpret: Optional[bool] = None
-                          ) -> BatchPrepared:
+                          interpret: Optional[bool] = None,
+                          key_W=None) -> BatchPrepared:
     """Host-only half of :func:`dispatch_returns_batch`: marshal H
-    return streams into the lockstep layout without touching jax."""
+    return streams into the lockstep layout without touching jax.
+    ``key_W``: each stream's own mask width, where the caller knows it
+    (:func:`group_diag` counts the group's W where it does not)."""
     if interpret is None:
         interpret = _INTERPRET_DEFAULT
     geom, host_args, R_lens = pack_batch_operands(
         P, ret_slots, slot_ops, M, interpret=interpret)
-    return BatchPrepared(P, geom, host_args, R_lens, interpret)
+    return BatchPrepared(P, geom, host_args, R_lens, interpret,
+                         key_W=key_W)
 
 
 def _pipe_walk_on(device, host_args, geom, n_pass: int, interpret: bool,
@@ -788,7 +1132,7 @@ def _dispatch_words(prep: BatchPrepared) -> BatchInflight:
     obs.count("lockstep.word_groups")
     fl = BatchInflight(prep.P, prep.geom, prep.host_args, prep.R_lens,
                        {}, [], None, prep.interpret,
-                       device=prep.device)
+                       device=prep.device, key_W=prep.key_W)
     fl.body = "word"
     fl.word_out = out
     return fl
@@ -810,13 +1154,15 @@ def dispatch_prepared(prep: BatchPrepared) -> BatchInflight:
             obs.engine_fallback("word-walk", type(e).__name__,
                                 lanes=prep.geom[4])
     W = prep.geom[1]
-    n_fast = min(W, _FAST_PASSES)
+    n_fast = _first_passes(W, prep.geom[2], prep.geom[3])
     dsegs: dict = {}
     ckpts, final = _pipe_walk_on(prep.device, prep.host_args, prep.geom,
                                  n_fast, prep.interpret, dsegs)
+    if _wide_tile(W, prep.geom[2], prep.geom[3]):
+        obs.count("lockstep.wide_groups")
     return BatchInflight(prep.P, prep.geom, prep.host_args, prep.R_lens,
                          dsegs, ckpts, final, prep.interpret,
-                         device=prep.device)
+                         device=prep.device, key_W=prep.key_W)
 
 
 def dispatch_returns_batch(P: np.ndarray, ret_slots: List[np.ndarray],
@@ -860,8 +1206,8 @@ def collect_returns_batch(fl: BatchInflight) -> np.ndarray:
             _R, any_dead, first = fl.word_out
             any_np = np.asarray(any_dead)
             first_np = np.asarray(first)
-            dead = np.full(H, -1, np.int64)
-            for h in np.nonzero(any_np)[0]:
+            dead = np.full(len(R_lens), -1, np.int64)
+            for h in np.nonzero(any_np[:len(R_lens)])[0]:
                 # exact per-step death (identity pads cannot kill a
                 # live set), clamped to the lane's real length
                 dead[int(h)] = min(int(first_np[int(h)]),
@@ -876,9 +1222,9 @@ def collect_returns_batch(fl: BatchInflight) -> np.ndarray:
                                 lanes=H, collect=True)
             redo = BatchPrepared(P, geom, host_args, R_lens,
                                  interpret, device=fl.device,
-                                 body="dense")
+                                 body="dense", key_W=fl.key_W)
             return collect_returns_batch(dispatch_prepared(redo))
-    n_fast = min(W, _FAST_PASSES)
+    n_fast = _first_passes(W, M, S)
     ckpts, final = fl.ckpts, fl.final
     HS = H * S
     lazy = transfer.lazy_fetch_enabled()
@@ -888,6 +1234,7 @@ def collect_returns_batch(fl: BatchInflight) -> np.ndarray:
 
         def _eager(fn):
             obs.count("fetch.eager")
+            fn = np.asarray(fn, np.float32)
             return np.array([fn[:, h * S:(h + 1) * S].any()
                              for h in range(H)])
 
@@ -919,12 +1266,14 @@ def collect_returns_batch(fl: BatchInflight) -> np.ndarray:
         ckpts, final = _pipe_walk_on(fl.device, host_args, geom, W,
                                      interpret, dsegs)
         alive = _alive_of(final)
-    dead = np.full(H, -1, np.int64)
+    # pad lanes (wide-M groups) stay alive: only the real lanes answer
+    alive = alive[:len(R_lens)]
+    dead = np.full(len(R_lens), -1, np.int64)
     if alive.all():
         return dead
     # localization: fetch the block checkpoints once, then re-walk the
     # death block of each dead history in ITS OWN geometry
-    ckpt_np = np.concatenate([np.asarray(c) for c in ckpts])
+    ckpt_np = np.concatenate([np.asarray(c, np.float32) for c in ckpts])
     n_blocks = R_pad // B
     ckpt_np = ckpt_np[:n_blocks]                 # [blocks, M, HS]
     ops_rhw = np.asarray(host_args[0]).reshape(R_pad, H, W)

@@ -7,8 +7,9 @@ Nothing runs: the kernels are lowered from shapes and compiled for a
 ``v5e:2x2`` topology that is described, not attached. The geometries
 are the ones the chip smoke's phases dispatch (``chip_smoke.py``):
 the cas-100k history (73k returns, W=5, M=32, S=8) on the lane and
-chunk-lockstep engines and the word walk, and the 4096-key x 100-op
-batch on the lockstep and keyed kernels. Every geometry is derived
+chunk-lockstep engines and the word walk, the 4096-key x 100-op
+batch on the lockstep and keyed kernels, and the wide-M lockstep
+kernel at the mask widths crashed ops give keyed keys (W=11-14). Every geometry is derived
 through the production sizing helpers, so a change there is compiled
 here.
 
@@ -132,6 +133,25 @@ def test_batch_kernel_keyed_batch(one_chip):
     # P crosses the wire already in the compute dtype
     _compile(run, one_chip, _sextet(seg * H * W), ((seg, H), jnp.int8),
              ((O1, S, S), jnp.dtype(cdt)), ((M * H * S // 8,), jnp.uint8))
+
+
+@pytest.mark.parametrize("w", [11, 12, 13, 14])
+def test_batch_kernel_wide_keys(one_chip, w):
+    """The lockstep kernel at the geometry ``lockstep_geometry`` picks
+    for keys whose crashed ops hold slots to the end (the partition
+    deployment's W 11-14): the wide-M kernel, its config set in VMEM
+    under the scoped limit it asks for, one block of 100-op keys."""
+    geo = reach_batch.lockstep_geometry(w, S)
+    assert geo.wide
+    m = 1 << w
+    B, R_pad = reach_batch.group_geom(R_KEY, geo.H, w)
+    cdt = reach_batch._COMPUTE_DTYPE if geo.H * S >= 128 else "float32"
+    run = reach_batch._batch_call(
+        B, w, m, S, geo.H, O1, R_pad, min(w, reach_lane._FAST_PASSES),
+        False, cdt, tile=reach_batch._wide_tile(w, m, S))
+    _compile(run, one_chip, _sextet(R_pad * geo.H * w),
+             ((R_pad, geo.H), jnp.int8), ((O1, S, S), jnp.dtype(cdt)),
+             ((m * geo.H * S // 8,), jnp.uint8))
 
 
 def _batch_at_block(sharding, B: int, lanes: int, states: int):
