@@ -1284,12 +1284,20 @@ def _result_invalid(engine: str, stream: ev.EventStream, memo: Memo,
 
 def _final_configs(memo: Memo, rs: "ev.ReturnStream", P_np: np.ndarray,
                    S_pad: int, M: int, W: int, dead_ret: int,
-                   limit: int = 16) -> List[Dict[str, Any]]:
+                   limit: int = 16,
+                   start: Optional[Tuple[int, np.ndarray]] = None
+                   ) -> List[Dict[str, Any]]:
     """Decode the configurations that survived up to (but not through)
     the dead return — the analogue of knossos's ``:final-paths``: each
     entry is a reachable model state plus the pending ops it has already
     linearized. Together they show every way the search tried to order
     the window, and that none admits the failing return.
+
+    ``start = (ret_lo, R_lo)`` hands over the exact config set
+    ``R_lo`` (bool ``[S_pad, M]``) that holds before return ``ret_lo``:
+    the walk then covers only ``[ret_lo, dead_ret)``, and yields the
+    same set as the walk of ``[0, dead_ret)`` from the initial state
+    that runs without it.
 
     The prefix and P's op axis pad to powers of two (identity returns;
     zero rows past the real ops, so the last row stays the all-zero
@@ -1297,17 +1305,21 @@ def _final_configs(memo: Memo, rs: "ev.ReturnStream", P_np: np.ndarray,
     compiles the walk a handful of times instead of once per key."""
     import jax.numpy as jnp
 
+    lo = 0 if start is None else int(start[0])
     xor_cols, bitmask = _xor_bitmask(W, M)
-    L = _next_pow2(max(dead_ret, _UNROLL))
+    L = _next_pow2(max(dead_ret - lo, _UNROLL))
     O_pad = _next_pow2(P_np.shape[0])
     P_np = np.pad(P_np, ((0, O_pad - P_np.shape[0]), (0, 0), (0, 0)))
     prefix = ev.pad_returns(
-        ev.ReturnStream(ret_slot=rs.ret_slot[:dead_ret],
-                        slot_ops=rs.slot_ops[:dead_ret],
-                        ret_event=rs.ret_event[:dead_ret],
-                        ret_entry=rs.ret_entry[:dead_ret],
-                        W=W, n_returns=dead_ret), L)
-    R0 = jnp.zeros((S_pad, M), jnp.bool_).at[0, 0].set(True)
+        ev.ReturnStream(ret_slot=rs.ret_slot[lo:dead_ret],
+                        slot_ops=rs.slot_ops[lo:dead_ret],
+                        ret_event=rs.ret_event[lo:dead_ret],
+                        ret_entry=rs.ret_entry[lo:dead_ret],
+                        W=W, n_returns=dead_ret - lo), L)
+    if start is None:
+        R0 = jnp.zeros((S_pad, M), jnp.bool_).at[0, 0].set(True)
+    else:
+        R0 = jnp.asarray(start[1], jnp.bool_)
     _, R, _, _ = _jitted_walk_returns()(
         jnp.asarray(P_np), jnp.asarray(xor_cols), jnp.asarray(bitmask),
         jnp.asarray(prefix.ret_slot), jnp.asarray(prefix.slot_ops), R0)
@@ -1324,13 +1336,16 @@ def _final_configs(memo: Memo, rs: "ev.ReturnStream", P_np: np.ndarray,
 
 
 def _attach_witness(out: Dict[str, Any], memo: Memo, rs, P_np, S_pad, M,
-                    W, dead_ret: int, packed: h.PackedHistory) -> None:
+                    W, dead_ret: int, packed: h.PackedHistory,
+                    start: Optional[Tuple[int, np.ndarray]] = None
+                    ) -> None:
     """Enrich an invalid verdict with knossos-style failure evidence:
-    ``final-configs`` (:func:`_final_configs`) and ``previous-ok`` (the
-    last successfully linearized return before the failing one)."""
+    ``final-configs`` (:func:`_final_configs`, walked from ``start``
+    when given) and ``previous-ok`` (the last successfully linearized
+    return before the failing one)."""
     try:
         out["final-configs"] = _final_configs(
-            memo, rs, P_np, S_pad, M, W, dead_ret)
+            memo, rs, P_np, S_pad, M, W, dead_ret, start=start)
         if dead_ret > 0:
             prev = packed.entries[int(rs.ret_entry[dead_ret - 1])]
             out["previous-ok"] = prev.op.to_dict()
@@ -1496,7 +1511,7 @@ def check_packed(model: Model, packed: h.PackedHistory, *,
                 try:
                     with obs.span("reach.walk", engine="reach-chunklock",
                                   returns=int(rs.n_returns)):
-                        dead, diag = rcl.walk_chunklock(
+                        dead, diag, seed = rcl.walk_seeded(
                             P_np, rs.ret_slot, rs.slot_ops, M)
                     elapsed = _time.monotonic() - t0
                     if dead < 0:
@@ -1508,10 +1523,8 @@ def check_packed(model: Model, packed: h.PackedHistory, *,
                         "reach-chunklock", stream, memo, packed,
                         int(rs.ret_event[dead]), elapsed)
                     out.update(diag)
-                    with obs.span("reach.witness",
-                                  engine="reach-chunklock"):
-                        _attach_witness(out, memo, rs, P_np, S_pad, M,
-                                        W, int(dead), packed)
+                    rcl.attach_witness(out, memo, rs, P_np, S_pad, M,
+                                       W, int(dead), packed, seed)
                     return out
                 except Exception as e:                  # noqa: BLE001
                     _warn_pallas_failed(f"chunklock: {e!r}")

@@ -268,11 +268,21 @@ def _localize(P: np.ndarray, ret_slot: np.ndarray,
     return -1, np.asarray(r_final).T.reshape(M * S)
 
 
+def _seed(v_entry: np.ndarray, c: int, per: int, M: int
+          ) -> Tuple[int, np.ndarray]:
+    """Witness seed of a death in chunk ``c``: ``(ret_lo, R_lo)``, the
+    chunk's first return and its exact entry set as bool ``[S, M]``
+    (the layout :func:`_localize` walks from), for
+    :func:`reach._final_configs`'s ``start``."""
+    return c * per, v_entry.reshape(M, -1).T
+
+
 def _host_fold(P: np.ndarray, ret_slot: np.ndarray,
                slot_ops: np.ndarray, M: int, seeds_np: np.ndarray,
                images_np: np.ndarray, v: np.ndarray, start: int,
                C: int, per: int, interpret: bool,
-               diag: Dict[str, Any]) -> int:
+               diag: Dict[str, Any]
+               ) -> Tuple[int, Optional[Tuple[int, np.ndarray]]]:
     """Host-side exact fold over the per-chunk seed/image summaries —
     the ONE recovery/combination loop (ISSUE 19) shared by the
     single-process inexact rescue and the multi-host gathered fold.
@@ -280,7 +290,8 @@ def _host_fold(P: np.ndarray, ret_slot: np.ndarray,
     :func:`_fold_call` wherever that fold is exact; chunks whose
     selected union seeds escape the exact boundary set are re-walked
     sequentially (:func:`_localize`). Returns the global dead return
-    index, -1 = linearizable."""
+    index (-1 = linearizable) and, on a death, the dead chunk's exact
+    entry set as a witness seed (:func:`_seed`)."""
     for c in range(start, C):
         active = seeds_np[c] @ v > 0             # [e_pad] selected
         sel = active @ seeds_np[c] > 0
@@ -291,16 +302,16 @@ def _host_fold(P: np.ndarray, ret_slot: np.ndarray,
             dead, vn = _localize(P, ret_slot, slot_ops, M, v, c, per,
                                  interpret)
             if dead >= 0:
-                return dead
+                return dead, _seed(v, c, per, M)
         if not vn.any():
             dead, _ = _localize(P, ret_slot, slot_ops, M, v, c, per,
                                 interpret)
             if dead < 0:
                 raise ChunklockUnfit(
                     "fold death not confirmed by re-walk")
-            return dead
+            return dead, _seed(v, c, per, M)
         v = vn
-    return -1
+    return -1, None
 
 
 def _walk_dist(shard, P: np.ndarray, ret_slot: np.ndarray,
@@ -400,8 +411,8 @@ def _walk_dist(shard, P: np.ndarray, ret_slot: np.ndarray,
     counts = np.asarray(cnt_d).astype(np.int64)
     v0 = np.zeros(MS, bool)
     v0[0] = True
-    dead = _host_fold(P, ret_slot, slot_ops, M, seeds_np, images_np,
-                      v0, 0, C, per, interpret, diag)
+    dead, _ = _host_fold(P, ret_slot, slot_ops, M, seeds_np, images_np,
+                         v0, 0, C, per, interpret, diag)
     obs.gauge("dist.processes", Pn)
     diag["basis-max"] = int(counts.max(initial=0))
     diag["dist"] = {"processes": Pn, "local_chunks": [int(lo), int(hi)],
@@ -423,7 +434,7 @@ class ChunklockInflight:
     the NEXT history's chunks while this one's fold drains.  The
     multi-host shard path is inherently synchronous (the DCN gather IS
     the fetch), so there ``result`` is already materialized and
-    ``collect`` just hands it back."""
+    ``collect`` just hands it back, with no witness seed."""
 
     __slots__ = ("packed", "final_b", "seeds_d", "P", "ret_slot",
                  "slot_ops", "M", "C", "e_pad", "per", "interpret",
@@ -553,13 +564,17 @@ def launch_chunklock(P: np.ndarray, ret_slot: np.ndarray,
 
 
 def collect_chunklock(inf: "ChunklockInflight"
-                      ) -> Tuple[int, Dict[str, Any]]:
+                      ) -> Tuple[int, Dict[str, Any],
+                                 Optional[Tuple[int, np.ndarray]]]:
     """Collect half: fetch the fold's packed verdict words (the ONE
     round trip) and run the verdict / localize / host-refold tail.
     Bit-identical to the pre-split walk — the split moves only WHEN
-    the fetch blocks, never what is fetched."""
+    the fetch blocks, never what is fetched. Returns ``(dead, diag,
+    seed)``: ``seed`` is a death's witness seed (:func:`_seed`; None
+    when valid, and on the multi-host path, whose witness decodes from
+    the initial state)."""
     if inf.result is not None:
-        return inf.result
+        return (*inf.result, None)
     P, ret_slot, slot_ops = inf.P, inf.ret_slot, inf.slot_ops
     M, C, e_pad, per = inf.M, inf.C, inf.e_pad, inf.per
     interpret, final_b, seeds_d = inf.interpret, inf.final_b, \
@@ -577,7 +592,7 @@ def collect_chunklock(inf: "ChunklockInflight"
     if not inexact[:last].any():
         # fold exact up to the deciding chunk
         if dead_chunk < 0:
-            return -1, diag
+            return -1, diag, None
         # death under an exact (or chunk-local over-approx) entry set
         # is a true death — localize the exact return inside the chunk
         dead, _ = _localize(P, ret_slot, slot_ops, M,
@@ -585,7 +600,7 @@ def collect_chunklock(inf: "ChunklockInflight"
                             interpret)
         if dead < 0:        # defensive: fold/walk disagreement
             raise ChunklockUnfit("fold death not confirmed by re-walk")
-        return dead, diag
+        return dead, diag, _seed(all_v[dead_chunk], dead_chunk, per, M)
     # rescue path: refold host-side from the first flagged chunk,
     # re-walking any chunk whose selected union seeds escape the exact
     # boundary set (only overflow chunks — |v̂| > e_pad — can flag)
@@ -594,9 +609,10 @@ def collect_chunklock(inf: "ChunklockInflight"
     images_np = fb.reshape(e_pad, M, C, S).transpose(2, 0, 1, 3) \
         .reshape(C, e_pad, MS)
     start = int(np.nonzero(inexact)[0][0])
-    dead = _host_fold(P, ret_slot, slot_ops, M, seeds_np, images_np,
-                      all_v[start], start, C, per, interpret, diag)
-    return dead, diag
+    dead, seed = _host_fold(P, ret_slot, slot_ops, M, seeds_np,
+                            images_np, all_v[start], start, C, per,
+                            interpret, diag)
+    return dead, diag, seed
 
 
 def walk_chunklock(P: np.ndarray, ret_slot: np.ndarray,
@@ -614,9 +630,41 @@ def walk_chunklock(P: np.ndarray, ret_slot: np.ndarray,
     (-1 = linearizable), bit-identical to
     :func:`reach_lane.walk_returns`; ``diag`` carries chunk geometry
     and rescue counts."""
-    return collect_chunklock(launch_chunklock(
-        P, ret_slot, slot_ops, M, n_chunks=n_chunks, e_pad=e_pad,
-        suffix=suffix, interpret=interpret, shard=shard))
+    return walk_seeded(P, ret_slot, slot_ops, M, n_chunks=n_chunks,
+                       e_pad=e_pad, suffix=suffix, interpret=interpret,
+                       shard=shard)[:2]
+
+
+def walk_seeded(P: np.ndarray, ret_slot: np.ndarray,
+                slot_ops: np.ndarray, M: int, **kw
+                ) -> Tuple[int, Dict[str, Any],
+                           Optional[Tuple[int, np.ndarray]]]:
+    """:func:`walk_chunklock` (same keywords) returning
+    ``(dead, diag, seed)``: ``seed`` is the dead chunk's exact entry
+    set for :func:`attach_witness` (None when valid or multi-host).
+    ``diag`` stays free of arrays."""
+    return collect_chunklock(launch_chunklock(P, ret_slot, slot_ops, M,
+                                              **kw))
+
+
+def attach_witness(out: Dict[str, Any], memo, rs, P_np: np.ndarray,
+                   S_pad: int, M: int, W: int, dead: int, packed,
+                   seed: Optional[Tuple[int, np.ndarray]]) -> None:
+    """The chunk-lockstep route's witness decode: walk only the dead
+    chunk's returns before ``dead``, from the entry set ``seed`` the
+    walk handed back; without a seed, the whole prefix from the
+    initial state. Either way the same ``final-configs``."""
+    from jepsen_tpu.checkers import reach
+
+    lo = 0 if seed is None else int(seed[0])
+    with obs.span("reach.witness", engine="reach-chunklock",
+                  walked=int(dead) - lo):
+        if seed is None:
+            obs.count("witness.unseeded")
+        else:
+            obs.count("witness.seeded")
+        reach._attach_witness(out, memo, rs, P_np, S_pad, M, W, dead,
+                              packed, start=seed)
 
 
 def check_packed(model, packed, *, max_states: int = 100_000,
@@ -650,7 +698,7 @@ def check_packed(model, packed, *, max_states: int = 100_000,
     if rs.n_returns < 2:
         raise ChunklockUnfit("too few returns")
     P_np = reach._build_P(memo, S_pad)
-    dead, diag = walk_chunklock(
+    dead, diag, seed = walk_seeded(
         P_np, rs.ret_slot, rs.slot_ops, M, n_chunks=n_chunks,
         e_pad=e_pad, suffix=suffix, interpret=interpret,
         shard=process_shard)
@@ -662,9 +710,8 @@ def check_packed(model, packed, *, max_states: int = 100_000,
         out = reach._result_invalid("reach-chunklock", stream, memo,
                                     packed, int(rs.ret_event[dead]),
                                     elapsed)
-        with obs.span("reach.witness", engine="reach-chunklock"):
-            reach._attach_witness(out, memo, rs, P_np, S_pad, M, W,
-                                  int(dead), packed)
+        attach_witness(out, memo, rs, P_np, S_pad, M, W, int(dead),
+                       packed, seed)
     out.update(diag)
     return out
 
