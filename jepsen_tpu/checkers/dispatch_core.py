@@ -1,12 +1,11 @@
 """Shared lockstep dispatch/collect core (ROADMAP item 1 down-payment).
 
-The keyed lockstep schedulers (:func:`reach._dispatch_lockstep_groups`
-and :func:`reach._dispatch_lockstep_stream`) and the chunk-lockstep
-engine (:func:`reach_chunklock.walk_chunklock`) each grew their own copy
-of the same pack→dispatch→fallback→recovery state machine. This module
-is that seam extracted ONCE, so engine variants — including the
-multi-host chunk-sharded path — parameterize it instead of adding a
-sixth choreography:
+The keyed lockstep lane (:func:`reach._stage_lockstep`) and the
+chunk-lockstep engine (:func:`reach_chunklock.walk_chunklock`) once
+each grew their own copy of the same pack→dispatch→fallback→recovery
+state machine. This module is that seam extracted ONCE, so engine
+variants — including the multi-host chunk-sharded path — parameterize
+it instead of adding another choreography:
 
 - :class:`DispatchState` — round-robin device placement over the mesh,
   pad-lane dedup accounting, the in-flight window and FIFO drain
@@ -108,13 +107,13 @@ def inflight_ready(fl) -> bool:
 
 
 class DispatchState:
-    """Shared per-dispatch bookkeeping of the synchronous and streaming
-    lockstep schedulers: round-robin device placement over the mesh,
-    pad-lane dedup accounting (mesh pad lanes are cross-group
-    duplicates — their returns must not count as real work), the
-    in-flight window, and the FIFO drain. ONE implementation so the two
-    schedulers' diag/obs output — which the stream-vs-sync differential
-    tests treat as equivalent — cannot drift."""
+    """Per-dispatch bookkeeping of the keyed lockstep lane
+    (:func:`reach._stage_lockstep`): round-robin device placement over
+    the mesh, pad-lane dedup accounting (mesh pad lanes are
+    cross-group duplicates — their returns must not count as real
+    work), the in-flight window, and the FIFO drain. The lane places
+    and admits each group as its prep arrives, drains to ``depth`` as
+    it goes, and its staged handle drains the rest on collect."""
 
     __slots__ = ("devs", "n_dev", "depth", "dead", "seen", "dev_groups",
                  "inflight", "inflight_hwm", "fetch_s",
@@ -179,21 +178,6 @@ class DispatchState:
         obs.count("pipeline.staged")
         return gd
 
-    def stage(self, gi: int, g, prep, dispatch_fn) -> dict:
-        """The pipeline's STAGE half for one group: device placement +
-        ``dispatch_fn(prep)`` (host pack already done by the caller's
-        prepare; this queues the puts/compiles/kernel launch, fetching
-        nothing) + in-flight admission. Returns the group diag."""
-        di, sp = self.place(gi, g, prep)
-        with obs.span("lockstep.dispatch", **sp):
-            fl = dispatch_fn(prep)
-        return self.admit(g, fl, di)
-
-    def collect(self, limit: int = 0) -> None:
-        """The pipeline's COLLECT half: FIFO-fetch verdicts until at
-        most ``limit`` groups remain in flight (0 = drain all)."""
-        self.drain(limit)
-
     def collect_ready(self, limit: int = 0) -> None:
         """Readiness-polled collect: FIFO-fetch only groups whose
         device results are already resident, stopping at the first
@@ -207,6 +191,8 @@ class DispatchState:
             self.drain(len(self.inflight) - 1)
 
     def drain(self, limit: int) -> None:
+        """FIFO-fetch verdicts until at most ``limit`` groups remain in
+        flight (0 = drain all)."""
         from jepsen_tpu.checkers import reach_batch
 
         while len(self.inflight) > limit:

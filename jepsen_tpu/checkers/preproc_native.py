@@ -10,7 +10,7 @@ Thread-safety contract: the stateless entry points (everything except
 :class:`Monitor`, which owns mutable C++ state) take only caller-owned
 buffers and keep no globals beyond the loaded library handle, and
 ctypes releases the GIL for the call's duration — which is what lets
-the streaming prep thread (``reach._dispatch_lockstep_stream``) run
+the streaming prep thread (``reach._stage_lockstep``) run
 :func:`build_keyed` per dispatch group while the main thread drives
 jax, with the two genuinely overlapping.
 """

@@ -1746,9 +1746,10 @@ class _UnionPrepA:
     and noop classification — plus each live key's packed arrays with
     op ids remapped into the union alphabet, the inputs stage B's
     per-group native packing (:func:`_union_pack_group`) consumes.
-    Pure host data; safe to share with the streaming prep thread."""
+    Pure host data; safe to share with the lockstep lane's prep
+    thread."""
     __slots__ = ("memo_u", "S_pad", "noop_op", "opids", "invs", "rets",
-                 "crs", "key_W", "_P", "_cats", "pack_s")
+                 "crs", "key_W", "_P", "_cats")
 
     def __init__(self, memo_u, S_pad, noop_op, opids, invs, rets, crs):
         self.memo_u = memo_u
@@ -1764,23 +1765,21 @@ class _UnionPrepA:
             invs, rets, crs, [c & noop_op[o] for c, o in zip(crs, opids)])
         self._P = None
         self._cats = None
-        # cumulative stage-B wall (native packing) over this batch —
-        # the synchronous scheduler's prep.wall_s base, so stream and
-        # sync report the SAME quantity (packing + marshalling)
-        self.pack_s = 0.0
 
     def P(self) -> np.ndarray:
-        """Union transition tensor, built once on first use (streaming
-        and synchronous consumers share it)."""
+        """Union transition tensor, built once on first use (the
+        lockstep lane and the whole-batch :func:`_union_prep` share
+        it)."""
         if self._P is None:
             self._P = _build_P(self.memo_u, self.S_pad)
         return self._P
 
     def cats(self):
         """Full-batch concatenations ``(inv, ret, opid, crs, offs)``,
-        built once — the synchronous whole-batch stage B and the
-        result-assembly accounting both need them, and re-concatenating
-        per consumer was a multi-hundred-MB memcpy at 4096×100k."""
+        built once — the whole-batch stage B and the result-assembly
+        accounting of :func:`_union_prep`'s lanes both need them, and
+        re-concatenating per consumer was a multi-hundred-MB memcpy at
+        4096×100k."""
         if self._cats is None:
             offs = np.zeros(len(self.opids) + 1, np.int64)
             for j in range(len(self.opids)):
@@ -1793,7 +1792,7 @@ class _UnionPrepA:
 
     def drop_per_key(self) -> np.ndarray:
         """Per-live-key count of dropped crashed-noop entries (the
-        events accounting of :func:`_union_results`)."""
+        events accounting of :func:`_union_results_parts`)."""
         return np.array(
             [int((self.crs[j] & self.noop_op[self.opids[j]]).sum())
              for j in range(len(self.opids))], np.int64)
@@ -1883,9 +1882,9 @@ def _union_pack_group(sa: "_UnionPrepA", sel: Sequence[int],
                       max_slots: int):
     """Stage B: native packing (``preproc_native.build_keyed``) of the
     keys at positions ``sel`` of the live axis — per dispatch group in
-    the streaming pipeline, or all live keys at once on the
-    synchronous path. Returns ``(ret_flat, ops_flat, key_W, key_R,
-    offsets, W)`` or None (native lib missing, or slot overflow under
+    the lockstep lane (:func:`_stage_lockstep`), or all live keys at
+    once for :func:`_union_prep`. Returns ``(ret_flat, ops_flat,
+    key_W, key_R, offsets, W)`` or None (native lib missing, or slot overflow under
     the union memo's coarser noop classification — union-noop ⊆
     per-key-noop, so a key near the max_slots boundary can overflow
     here yet fit the general per-key path; genuine overflow raises
@@ -1893,11 +1892,10 @@ def _union_pack_group(sa: "_UnionPrepA", sel: Sequence[int],
     (numpy + the GIL-releasing native lib): safe on the prep thread."""
     from jepsen_tpu.checkers import preproc_native
 
-    t0 = _time.monotonic()
     sel = list(sel)
     if sel == list(range(len(sa.opids))):
-        # whole-batch selection (the synchronous path): reuse stage
-        # A's cached concatenations instead of re-building them
+        # whole-batch selection (:func:`_union_prep`): reuse stage A's
+        # cached concatenations instead of re-building them
         inv_c, ret_c, opid_c, crs_c, offs = sa.cats()
     else:
         offs = np.zeros(len(sel) + 1, np.int64)
@@ -1910,7 +1908,6 @@ def _union_pack_group(sa: "_UnionPrepA", sel: Sequence[int],
     built = preproc_native.build_keyed(
         offs, inv_c, ret_c, opid_c, crs_c,
         sa.noop_op, max_slots, max_slots)
-    sa.pack_s += _time.monotonic() - t0
     if built is None:
         return None
     ret_flat, ops_wide, _pend, key_W, key_R, _ret_entry, _R_tot = built
@@ -1931,35 +1928,17 @@ def _lockstep_fits(S_pad: int, W: int, M: int, n_ops: int) -> bool:
             and reach_batch.lockstep_geometry(W, S_pad) is not None)
 
 
-def _group_streams(ret_flat, ops_flat, offsets, g, W: int):
-    """Group ``g``'s return streams (live-key positions into the flat
-    union build) at mask width ``W`` (its planned width,
-    :func:`reach_batch.group_widths`): ``(ret_slots, slot_ops, M)``."""
-    return ([ret_flat[offsets[k]:offsets[k + 1]] for k in g],
-            [ops_flat[offsets[k]:offsets[k + 1], :W] for k in g],
-            1 << W)
-
-
-def _plan_union_groups(u, group: int) -> List[List[int]]:
-    """Lockstep groups of a full :func:`_union_prep` tuple, planned by
-    length and by each key's mask width."""
-    from jepsen_tpu.checkers import reach_batch
-    S_pad, W, key_W, key_R = u[1], u[3], u[7], u[8]
-    return reach_batch.plan_buckets([int(r) for r in key_R], W,
-                                    group=group, key_W=key_W, S=S_pad)
-
-
 def _union_prep(model: Model, packed_list: Sequence[h.PackedHistory],
                 live: Sequence[int], max_states: int, max_slots: int,
                 need_pallas: bool = True,
                 stage_a: Optional["_UnionPrepA"] = None):
     """Shared union-alphabet native preprocessing for the batched
-    device engines (keyed kernel and the lockstep batch kernel): ONE
-    memo over the union of every history's op alphabet + ONE native
-    call building every history's slotted return stream — composed
-    from the stage A / stage B split the streaming pipeline reuses
-    per-group (a prebuilt ``stage_a`` skips the union BFS, so a
-    streaming→synchronous fallback never pays it twice). Returns None
+    keyed lanes (the keyed kernel and the mesh-union walk): ONE memo
+    over the union of every history's op alphabet + ONE native call
+    building every history's slotted return stream — composed from
+    the stage A / stage B split the lockstep lane runs per group (a
+    prebuilt ``stage_a`` skips the union BFS, so a lane after a
+    lockstep decline never pays it twice). Returns None
     when the union explodes, ops are unhashable, the native lib is
     missing, the kernels' dense budgets don't fit, or a history
     overflows max_slots under the union memo's coarser noop
@@ -2080,96 +2059,46 @@ def check_batch(model: Model, packed_list: Sequence[h.PackedHistory], *,
     live = [i for i, r in enumerate(results) if r is None]
     if not live:
         return results  # type: ignore[return-value]
-    u = None
-    sa = None
+    cause = "no-union-prep"
     from jepsen_tpu.checkers import preproc_native
     if _use_pallas() and preproc_native.available() and len(live) >= 2:
         sa = _union_stage_a(model, packed_list, live, max_states)
         if sa is not None:
-            if _stream_prep_enabled():
-                # tentpole path: per-group packing streams from a prep
-                # thread while earlier groups walk on device
-                out = _check_lockstep_stream(
+            try:
+                staged = _stage_lockstep(
                     "reach-lockstep", model, packed_list, live, sa,
                     max_states, max_slots, max_dense,
                     group or _BATCH_GROUP, diag, t0)
-                if out is not None:
-                    return out
-            u = _union_prep(model, packed_list, live, max_states,
-                            max_slots, stage_a=sa)
-    if u is None:
-        # the ISSUE-named silent degradation point: the lockstep batch
-        # quietly became H sequential per-history checks
-        obs.engine_fallback("reach-lockstep", "no-union-prep",
-                            histories=len(live))
-        for i in live:
-            results[i] = check_packed(model, packed_list[i],
-                                      max_states=max_states,
-                                      max_slots=max_slots,
-                                      max_dense=max_dense)
-        return results  # type: ignore[return-value]
-    (memo_u, S_pad, P, W, M, ret_flat, ops_flat, key_W, key_R,
-     offsets, opid_cat, crs_cat, offs, noop_op) = u
-    try:
-        # length-bucketed lane packing + pipelined group dispatch: a
-        # ragged batch no longer pads every history to the longest,
-        # and group g+1's marshalling/compile hides under group g's
-        # device walk
-        groups = _plan_union_groups(u, group)
-        dead = _dispatch_lockstep_groups(
-            P, ret_flat, ops_flat, offsets, groups, key_W, len(live),
-            diag, prep_base_s=sa.pack_s if sa is not None else 0.0)
-    except Exception as e:                              # noqa: BLE001
-        _warn_pallas_failed(repr(e))
-        obs.engine_fallback("reach-lockstep", type(e).__name__,
-                            histories=len(live))
-        for i in live:
-            results[i] = check_packed(model, packed_list[i],
-                                      max_states=max_states,
-                                      max_slots=max_slots,
-                                      max_dense=max_dense)
-        return results  # type: ignore[return-value]
-    elapsed = _time.monotonic() - t0
-    return _union_results("reach-lockstep", model, packed_list, live,
-                          dead, u, elapsed, max_states, max_slots,
-                          max_dense)
+                if staged is not None:
+                    return staged.collect()
+            except Exception as e:                      # noqa: BLE001
+                _warn_pallas_failed(repr(e))
+                cause = type(e).__name__
+    # the lockstep batch becomes H sequential per-history checks —
+    # never silently
+    obs.engine_fallback("reach-lockstep", cause, histories=len(live))
+    for i in live:
+        results[i] = check_packed(model, packed_list[i],
+                                  max_states=max_states,
+                                  max_slots=max_slots,
+                                  max_dense=max_dense)
+    return results  # type: ignore[return-value]
 
 
 def _union_stage_a_shared(model: Model, packed_list, live,
                           max_states: int, u_box: Optional[dict]
                           ) -> Optional["_UnionPrepA"]:
     """One :func:`_union_stage_a` per ``check_many`` call, shared by
-    the streaming pipeline, the synchronous lockstep lane, and the
-    keyed lane (the union BFS is the expensive half of the old
-    monolithic prep — a streaming→synchronous fallback must not pay
-    it twice). Caches the result — including a failed (None) one."""
+    the lockstep lane and the keyed lanes after it (the union BFS is
+    the expensive half of the prep — a lane after a lockstep decline
+    must not pay it twice). Caches the result — including a failed
+    (None) one."""
     if u_box is not None and "sa" in u_box:
         return u_box["sa"]
     sa = _union_stage_a(model, packed_list, live, max_states)
     if u_box is not None:
         u_box["sa"] = sa
     return sa
-
-
-def _union_prep_shared(model: Model, packed_list, live,
-                       max_states: int, max_slots: int,
-                       u_box: Optional[dict]):
-    """One :func:`_union_prep` per ``check_many`` call: the lockstep
-    and keyed lanes take identical ``(live, max_states, max_slots,
-    need_pallas=True)`` preps, so when the first lane declines (or its
-    kernel fails) the second must not pay the union-alphabet BFS +
-    native build again (~2 s of host time at 4096 keys). ``u_box``
-    caches the result — including a failed (None) prep — and reuses a
-    cached stage A from the streaming attempt."""
-    if u_box is not None and "u" in u_box:
-        return u_box["u"]
-    sa = _union_stage_a_shared(model, packed_list, live, max_states,
-                               u_box)
-    u = None if sa is None else _union_prep(
-        model, packed_list, live, max_states, max_slots, stage_a=sa)
-    if u_box is not None:
-        u_box["u"] = u
-    return u
 
 
 def _check_many_native(model: Model,
@@ -2198,8 +2127,12 @@ def _check_many_native(model: Model,
     total_returns = sum(packed_list[i].n_ok for i in live)
     if not live or total_returns < _PALLAS_MIN_RETURNS:
         return None
-    u = _union_prep_shared(model, packed_list, live, max_states,
-                           max_slots, u_box)
+    # the lockstep attempt's cached stage A: the union-alphabet BFS
+    # (~2 s of host time at 4096 keys) is paid once
+    sa = _union_stage_a_shared(model, packed_list, live, max_states,
+                               u_box)
+    u = None if sa is None else _union_prep(
+        model, packed_list, live, max_states, max_slots, stage_a=sa)
     if u is None:
         return None
     (memo_u, S_pad, P, W, M, ret_flat, ops_flat, key_W, key_R,
@@ -2225,9 +2158,12 @@ def _check_many_native(model: Model,
     dead_local = np.array(
         [int(d) - int(offsets[k]) if int(d) >= 0 else -1
          for k, d in enumerate(dead)], np.int64)
-    return _union_results("reach-keyed", model, packed_list, live,
-                          dead_local, u, elapsed, max_states,
-                          max_slots, max_dense)
+    drop_cat = (crs_cat & noop_op[opid_cat]).astype(np.int64)
+    return _union_results_parts("reach-keyed", model, packed_list, live,
+                                dead_local, memo_u, key_W, key_R,
+                                np.add.reduceat(drop_cat, offs[:-1]),
+                                elapsed, max_states, max_slots,
+                                max_dense)
 
 
 def _union_valid_result(engine: str, p: h.PackedHistory, dropped: int,
@@ -2242,24 +2178,6 @@ def _union_valid_result(engine: str, p: h.PackedHistory, dropped: int,
             "dropped-crashed-noops": dropped, "time-s": elapsed}
 
 
-def _union_results(engine: str, model: Model,
-                   packed_list: Sequence[h.PackedHistory],
-                   live: Sequence[int], dead_local: np.ndarray, u,
-                   elapsed: float, max_states: int, max_slots: int,
-                   max_dense: int) -> List[Dict[str, Any]]:
-    """Assemble per-history results from a full :func:`_union_prep`
-    tuple — thin adapter over :func:`_union_results_parts` for the
-    keyed/lockstep/mesh lanes that carry one."""
-    (memo_u, _S_pad, _P, _W, _M, _ret_flat, _ops_flat, key_W, key_R,
-     _offsets, opid_cat, crs_cat, offs, noop_op) = u
-    drop_cat = (crs_cat & noop_op[opid_cat]).astype(np.int64)
-    drop_per_key = np.add.reduceat(drop_cat, offs[:-1])
-    return _union_results_parts(engine, model, packed_list, live,
-                                dead_local, memo_u, key_W, key_R,
-                                drop_per_key, elapsed, max_states,
-                                max_slots, max_dense)
-
-
 def _union_results_parts(engine: str, model: Model,
                          packed_list: Sequence[h.PackedHistory],
                          live: Sequence[int], dead_local: np.ndarray,
@@ -2268,9 +2186,8 @@ def _union_results_parts(engine: str, model: Model,
                          max_states: int, max_slots: int,
                          max_dense: int) -> List[Dict[str, Any]]:
     """Assemble per-history results from union-geometry verdicts —
-    shared by the keyed and lockstep lanes of :func:`check_many`, by
-    :func:`check_batch`, and by the streaming pipeline (which carries
-    per-group ``key_W``/``key_R`` instead of a prep tuple).
+    shared by the lockstep lane (:func:`_stage_lockstep`, behind
+    :func:`check_many` and :func:`check_batch`) and the keyed lane.
     ``dead_local[k]`` is live history k's LOCAL dead return index
     (-1 = linearizable). Valid histories are answered from the union
     accounting; the rare failed history decodes in its OWN geometry
@@ -2312,17 +2229,16 @@ _LOCKSTEP_PIPE_DEPTH = dispatch_core.PIPE_DEPTH
 
 def _lockstep_accounting(gdiags: List[dict], prep_s: float,
                          hidden_s: float, stall_s: float,
-                         dispatch_s: float, fetch_s: float, mode: str,
+                         dispatch_s: float, fetch_s: float,
                          queue_hwm: int,
                          diag: Optional[dict],
                          mesh: Optional[dict] = None,
                          fetch_degraded: bool = False) -> None:
-    """Shared obs/diag accounting tail of the synchronous and streaming
-    lockstep schedulers: pack efficiency, kernel-cache counters, and
-    the prep/dispatch/fetch wall breakdown. ``prep.hidden_s`` is the
-    prep wall time that did NOT extend the critical path (prep minus
-    the consumer's queue stalls) — the overlap win as ONE tracked
-    number; on the synchronous path it is 0 by construction. ``mesh``
+    """Obs/diag accounting tail of the lockstep lane's collect: pack
+    efficiency, kernel-cache counters, and the prep/dispatch/fetch
+    wall breakdown. ``prep.hidden_s`` is the prep wall time that did
+    NOT extend the critical path (prep minus the consumer's queue
+    stalls) — the overlap win as ONE tracked number. ``mesh``
     (device-sharded dispatches only) carries the device count,
     per-device dispatched-group counts, and the in-flight high-water
     mark — the stream-overlap evidence of the multi-queue scheduler —
@@ -2350,7 +2266,7 @@ def _lockstep_accounting(gdiags: List[dict], prep_s: float,
     obs.gauge("prep.hidden_s", round(hidden_s, 6))
     obs.gauge("prep.stall_s", round(stall_s, 6))
     obs.gauge("prep.queue_depth_max", queue_hwm)
-    obs.gauge("prep.mode", mode)
+    obs.gauge("prep.mode", "stream")
     # transfer-diet evidence per dispatch: actual wire bytes vs the
     # blanket int32/f32 format, and which fetch protocol answered
     put_b = sum(d.get("put_bytes", 0) for d in gdiags)
@@ -2364,8 +2280,8 @@ def _lockstep_accounting(gdiags: List[dict], prep_s: float,
         obs.gauge("lockstep.mesh.devices", mesh["n_devices"])
         obs.gauge("lockstep.mesh.inflight_max", mesh["inflight_max"])
         if mesh.get("pad_lanes"):
-            # counted HERE — once per completed dispatch — so a
-            # stream→sync retry of the same batch can't double-count
+            # counted HERE — once per completed collect — so a batch
+            # handed to the next lane mid-stream never counts
             obs.count("lockstep.mesh.pad_lanes", mesh["pad_lanes"])
         for k, c in enumerate(mesh["per_device_groups"]):
             if c:
@@ -2378,7 +2294,7 @@ def _lockstep_accounting(gdiags: List[dict], prep_s: float,
         diag["kernel_cache"] = cache
         diag["dispatch_s"] = round(dispatch_s, 6)
         diag["fetch_s"] = round(fetch_s, 6)
-        diag["prep"] = {"mode": mode, "wall_s": round(prep_s, 6),
+        diag["prep"] = {"mode": "stream", "wall_s": round(prep_s, 6),
                         "hidden_s": round(hidden_s, 6),
                         "stall_s": round(stall_s, 6),
                         "queue_depth_max": queue_hwm,
@@ -2390,123 +2306,118 @@ def _lockstep_accounting(gdiags: List[dict], prep_s: float,
             diag["mesh"] = dict(mesh)
 
 
-# the shared dispatch/collect state machine now lives in
-# dispatch_core (both lockstep engines and the multi-host chunk path
-# parameterize ONE implementation); the alias keeps this module's
-# scheduler code and its historical name readable
+# the shared dispatch/collect state machine lives in dispatch_core;
+# the alias keeps this module's historical name readable
 _LockstepDispatchState = dispatch_core.DispatchState
 
 
-def _dispatch_lockstep_groups(P, ret_flat, ops_flat, offsets, groups,
-                              key_W, n_live: int,
-                              diag: Optional[dict] = None,
-                              prep_base_s: float = 0.0,
-                              devices: Optional[Sequence] = None,
-                              pad_lanes: int = 0) -> np.ndarray:
-    """Bucketed, pipelined lockstep dispatch (the SYNCHRONOUS
-    scheduler — the streaming pipeline's fallback and the verdict
-    reference of its differential tests): each group in ``groups``
-    (index lists into the live-key axis, from
-    :func:`reach_batch.plan_buckets`) walks the batch kernel in its own
-    geometry; group g+1's walk is QUEUED before group g's verdicts are
-    fetched, so host marshalling/compiles overlap device walks. The
-    per-geometry compiled-kernel cache (``reach_batch._batch_call``)
-    makes repeated geometries free across groups and calls. With
-    ``devices`` the groups (lane blocks, pre-split by
-    :func:`reach_batch.shard_groups_for_mesh`) are placed round-robin
-    over the mesh and the in-flight window widens to one walking plus
-    one queued group PER DEVICE — device k walks group g while device
-    j walks group g+1, and FIFO collection drains the oldest shard
-    while the rest keep walking. Fills ``diag`` (when given) with
-    per-group geometry, pack efficiency (real vs padded returns),
-    kernel-cache counters, and the prep/dispatch/fetch wall breakdown.
-    Each group walks at its planned mask width
-    (:func:`reach_batch.group_widths` of the live keys' ``key_W``).
-    Returns the per-live-key local dead indices."""
-    from jepsen_tpu.checkers import reach_batch
-
-    dead = np.full(n_live, -1, np.int64)
-    st = _LockstepDispatchState(devices, dead)
-    # prep_base_s carries the caller's stage-B packing wall
-    # (sa.pack_s) so sync prep.wall_s covers packing + marshalling —
-    # the same quantity the streaming scheduler reports
-    prep_s = prep_base_s
-    dispatch_s = 0.0
-    gdiags: List[dict] = []
-    widths = reach_batch.group_widths(groups, key_W, P.shape[1])
-    for gi, g in enumerate(groups):
-        t0 = _time.monotonic()
-        with obs.span("lockstep.prep", lanes=len(g)):
-            rets, ops, M = _group_streams(ret_flat, ops_flat, offsets,
-                                          g, widths[gi])
-            prep = reach_batch.prepare_returns_batch(
-                P, rets, ops, M, key_W=[key_W[k] for k in g])
-        t1 = _time.monotonic()
-        prep_s += t1 - t0
-        gdiags.append(st.stage(gi, g, prep,
-                               reach_batch.dispatch_prepared))
-        dispatch_s += _time.monotonic() - t1
-        st.collect(st.depth)
-    st.collect(0)
-    _lockstep_accounting(gdiags, prep_s, 0.0, 0.0, dispatch_s,
-                         st.fetch_s, "sync", 0, diag,
-                         st.mesh_info(pad_lanes), st.fetch_degraded)
-    return dead
-
-
-# bounded handoff between the streaming prep thread and the dispatch
-# loop: depth 2 keeps one marshalled group waiting while another packs,
-# without pinning unbounded host operand sets in memory
+# bounded handoff between the prep thread and the dispatch loop: depth
+# 2 keeps one marshalled group waiting while another packs, without
+# pinning unbounded host operand sets in memory
 _PREP_QUEUE_DEPTH = 2
 
 
-def _stream_prep_enabled() -> bool:
-    """The streaming prep→dispatch pipeline is on by default wherever
-    the lockstep lane runs; ``JEPSEN_TPU_NO_STREAM_PREP=1`` forces the
-    synchronous scheduler (consulted per call — tests toggle it)."""
-    return not os.environ.get("JEPSEN_TPU_NO_STREAM_PREP")
+class StagedMany:
+    """A keyed lockstep batch staged by :func:`_stage_lockstep`: every
+    dispatch group's walk is queued on device (host pack, puts and
+    kernel launches paid) and the newest groups' verdicts are not yet
+    fetched. ``collect()`` drains them FIFO, emits the lane's
+    accounting and assembles per-history results. The blocking callers
+    (:func:`check_many`, :func:`check_batch`, the mesh lane) collect at
+    once; a serve lane holds several handles in flight
+    (:func:`stage_check_many`), so its verdicts are the blocking ones
+    by construction. A collect-side device error propagates to the
+    caller's recovery ladder."""
+
+    __slots__ = ("st", "gdiags", "_collect")
+
+    def __init__(self, st, gdiags, collect_fn):
+        self.st = st
+        self.gdiags = gdiags
+        self._collect = collect_fn
+
+    def ready(self) -> bool:
+        """True when every group still in flight has its device results
+        resident (collect would not block on the walk)."""
+        return all(dispatch_core.inflight_ready(fl)
+                   for _g, fl, _di in self.st.inflight)
+
+    def collect(self) -> List[Dict[str, Any]]:
+        return self._collect()
 
 
-def _dispatch_lockstep_stream(sa: "_UnionPrepA", groups, widths,
-                              max_slots: int, n_live: int,
-                              diag: Optional[dict],
-                              devices: Optional[Sequence] = None,
-                              pad_lanes: int = 0):
-    """Streaming producer/consumer lockstep scheduler (the ISSUE 3
-    tentpole): a background prep thread runs per-group native packing
+def _lockstep_live(packed_list: Sequence[h.PackedHistory]
+                   ) -> Optional[List[int]]:
+    """The live keys of a batch the keyed lockstep lane of
+    :func:`check_many` takes, or None: the lane needs Pallas and the
+    native lib, two live keys, and enough returns to beat the keyed
+    and XLA walks."""
+    from jepsen_tpu.checkers import preproc_native
+
+    if not (_use_pallas() and preproc_native.available()):
+        return None
+    live = [i for i, p in enumerate(packed_list) if p.n and p.n_ok]
+    if (len(live) < 2 or sum(packed_list[i].n_ok for i in live)
+            < _PALLAS_MIN_RETURNS):
+        return None
+    return live
+
+
+def _stage_lockstep(engine: str, model: Model,
+                    packed_list: Sequence[h.PackedHistory],
+                    live: Sequence[int], sa: "_UnionPrepA",
+                    max_states: int, max_slots: int, max_dense: int,
+                    group: int, diag: Optional[dict], t0: float,
+                    devices: Optional[Sequence] = None
+                    ) -> Optional[StagedMany]:
+    """The keyed lockstep lane's one scheduler, behind
+    :func:`check_many`, :func:`check_batch`, the mesh lane and
+    :func:`stage_check_many`. Plans dispatch groups once, from the
+    per-key return counts (every non-crashed entry returns exactly
+    once, so ``n_ok`` IS the return count, known before any native
+    build) and stage A's per-key mask widths, so keys of different
+    mask cost walk in different groups; with more than one device the
+    groups are lane-sharded over the mesh
+    (:func:`reach_batch.shard_groups_for_mesh`), and each group walks
+    at its planned width (:func:`reach_batch.group_widths`).
+
+    A background prep thread runs per-group native packing
     (:func:`_union_pack_group`) and operand marshalling
     (:func:`reach_batch.prepare_returns_batch`) and feeds this thread
-    through a bounded queue — group 0 walks on device while groups
-    1..G are still being packed, extending the
-    ``dispatch_returns_batch``/``collect_returns_batch`` split
-    upstream into host prep. All jax work (device puts, compiles,
-    dispatches, fetches) stays on the calling thread; the producer
-    touches only numpy and the GIL-releasing native lib, so the two
-    genuinely overlap.
+    through a bounded queue, so group 0 walks on device while later
+    groups are still being packed. All jax work (device puts,
+    compiles, dispatches, fetches) stays on the calling thread; the
+    producer touches only numpy and the GIL-releasing native lib, so
+    the two genuinely overlap. Groups drain FIFO down to the dispatch
+    window as they go; with ``devices`` they are placed round-robin
+    over the mesh, one walking plus one queued group per device, so
+    the one prep thread feeds every chip.
 
-    Returns ``(dead, key_W, key_R)`` over the live axis, or None when
-    the producer declined (slot overflow / budget gates) or raised —
-    the caller falls back to the synchronous path, reusing stage A, so
-    verdicts stay bit-identical by construction. Exactly one
-    ``stream-prep`` fallback lands in the obs ledger on that path, and
-    the queue is drained so the producer can never deadlock on a full
-    queue. Overlap efficiency is tracked: ``prep.wall_s`` (total prep
-    thread work) vs ``prep.hidden_s`` (prep time that did not extend
-    the critical path — wall minus the consumer's queue stalls).
-
-    With ``devices`` the consumer becomes the MULTI-QUEUE dispatcher of
-    the mesh lockstep lane: arriving groups (lane blocks) are placed
-    round-robin over the mesh with one walking plus one queued group
-    per device, so the ONE prep thread feeds N concurrently-walking
-    chips — device k walks group g while device j walks group g+1 and
-    the producer packs g+2. FIFO collection drains the oldest shard
-    while the rest keep walking; fallback guarantees are unchanged
-    (the fallback target is the caller's, which for the mesh lane is
-    the single-device lockstep scheduler, never the keyed kernel)."""
+    Returns a :class:`StagedMany` once every group is dispatched, or
+    None when the producer declined (slot overflow, budget gates) or
+    raised: exactly one ``stream-prep`` fallback lands in the obs
+    ledger and the caller hands the batch to its next lane. The queue
+    is drained on the way out, so the producer can never deadlock on
+    a full queue. A dispatch-side error propagates to the caller,
+    whose next lane answers it. Overlap is tracked: ``prep.wall_s``
+    (prep-thread work) vs ``prep.hidden_s`` (the part that did not
+    extend the critical path: wall minus the consumer's queue
+    stalls)."""
     import queue as _queue
 
     from jepsen_tpu.checkers import reach_batch
 
+    lens = [int(packed_list[i].n_ok) for i in live]
+    # the planner's floor only needs a width HINT (a coarser floor
+    # splits small keys into more groups — suboptimal packing, never
+    # incorrect)
+    groups = reach_batch.plan_buckets(lens, max_slots, group=group,
+                                      key_W=sa.key_W, S=sa.S_pad)
+    pad_lanes = 0
+    if devices is not None and len(devices) > 1:
+        groups, pad_lanes = reach_batch.shard_groups_for_mesh(
+            groups, len(devices))
+    widths = reach_batch.group_widths(groups, sa.key_W, sa.S_pad)
     P = sa.P()
     q: "_queue.Queue" = _queue.Queue(maxsize=_PREP_QUEUE_DEPTH)
     stop = threading.Event()
@@ -2568,9 +2479,9 @@ def _dispatch_lockstep_stream(sa: "_UnionPrepA", groups, widths,
         except BaseException as e:                      # noqa: BLE001
             _put(("error", -1, e))
 
-    dead = np.full(n_live, -1, np.int64)
-    key_W_full = np.zeros(n_live, np.int32)
-    key_R_full = np.zeros(n_live, np.int32)
+    dead = np.full(len(live), -1, np.int64)
+    key_W_full = np.zeros(len(live), np.int32)
+    key_R_full = np.zeros(len(live), np.int32)
     st = _LockstepDispatchState(devices, dead)
     gdiags: List[dict] = []
     stall_s = dispatch_s = 0.0
@@ -2581,9 +2492,9 @@ def _dispatch_lockstep_stream(sa: "_UnionPrepA", groups, widths,
     th.start()
     try:
         while True:
-            t0 = _time.monotonic()
+            ta = _time.monotonic()
             kind, gi, payload = q.get()
-            stall_s += _time.monotonic() - t0
+            stall_s += _time.monotonic() - ta
             if kind == "done":
                 break
             if kind in ("decline", "error"):
@@ -2591,19 +2502,17 @@ def _dispatch_lockstep_stream(sa: "_UnionPrepA", groups, widths,
                 break
             prep, key_W, key_R = payload
             g = groups[gi]
-            t0 = _time.monotonic()
+            ta = _time.monotonic()
             di, sp = st.place(gi, g, prep)
             sp["streamed"] = True
             with obs.span("lockstep.dispatch", **sp):
                 fl = reach_batch.dispatch_prepared(prep)
-            dispatch_s += _time.monotonic() - t0
+            dispatch_s += _time.monotonic() - ta
             gdiags.append(st.admit(g, fl, di))
             idx = np.asarray(g, np.int64)
             key_W_full[idx] = key_W
             key_R_full[idx] = key_R
             st.drain(st.depth)
-        if failure is None:
-            st.drain(0)
     finally:
         stop.set()
         try:
@@ -2615,10 +2524,9 @@ def _dispatch_lockstep_stream(sa: "_UnionPrepA", groups, widths,
         th.join(timeout=30.0)
         if th.is_alive():
             # producer stuck inside a native pack: it is a daemon and
-            # touches only its own buffers (plus the cumulative
-            # sa.pack_s accounting), so abandoning it is safe — but a
-            # leaked thread racing the synchronous fallback's packing
-            # must never be invisible
+            # touches only its own buffers, so abandoning it is safe —
+            # but a leaked thread racing the next lane's packing must
+            # never be invisible
             obs.count("prep.thread_abandoned")
             obs.decision("stream-prep", "abandoned-thread",
                          groups=len(groups))
@@ -2628,80 +2536,27 @@ def _dispatch_lockstep_stream(sa: "_UnionPrepA", groups, widths,
     if failure is not None:
         kind, err = failure
         cause = type(err).__name__ if kind == "error" else "declined"
-        # the ISSUE-mandated record: a prep-thread failure degrades to
-        # the synchronous path exactly once, never silently
+        # exactly one record, then the caller's next lane
         obs.engine_fallback("stream-prep", cause, groups=len(groups))
         if kind == "error":
             logging.getLogger("jepsen.reach").warning(
-                "streaming prep failed (%r); falling back to the "
-                "synchronous lockstep path", err, exc_info=err)
+                "streaming prep failed (%r); handing the batch to the "
+                "next lane", err, exc_info=err)
         return None
     hidden_s = max(0.0, prep_wall[0] - stall_s)
-    _lockstep_accounting(gdiags, prep_wall[0], hidden_s, stall_s,
-                         dispatch_s, st.fetch_s, "stream",
-                         queue_hwm[0], diag, st.mesh_info(pad_lanes),
-                         st.fetch_degraded)
-    obs.count("prep.streamed_groups", len(gdiags))
-    return dead, key_W_full, key_R_full
 
+    def _collect() -> List[Dict[str, Any]]:
+        st.drain(0)
+        _lockstep_accounting(gdiags, prep_wall[0], hidden_s, stall_s,
+                             dispatch_s, st.fetch_s, queue_hwm[0], diag,
+                             st.mesh_info(pad_lanes), st.fetch_degraded)
+        obs.count("prep.streamed_groups", len(gdiags))
+        return _union_results_parts(
+            engine, model, packed_list, live, dead, sa.memo_u,
+            key_W_full, key_R_full, sa.drop_per_key(),
+            _time.monotonic() - t0, max_states, max_slots, max_dense)
 
-def _check_lockstep_stream(engine: str, model: Model,
-                           packed_list: Sequence[h.PackedHistory],
-                           live: Sequence[int], sa: "_UnionPrepA",
-                           max_states: int, max_slots: int,
-                           max_dense: int, group: int,
-                           diag: Optional[dict], t0: float,
-                           devices: Optional[Sequence] = None
-                           ) -> Optional[List[Dict[str, Any]]]:
-    """Run the streaming lockstep pipeline end to end: plan bucket
-    groups from the per-key return counts (every non-crashed entry
-    returns exactly once, so ``n_ok`` IS the return count — known
-    before any native build), stream prep→dispatch, assemble results.
-    With ``devices`` the planned groups are lane-sharded over the mesh
-    (:func:`reach_batch.shard_groups_for_mesh`) and the dispatcher
-    multi-queues them round-robin across chips. Returns None when
-    there is nothing to overlap (single group) or the pipeline fell
-    back — the caller then runs the synchronous path on the same
-    stage A, so verdicts are bit-identical."""
-    from jepsen_tpu.checkers import reach_batch
-
-    lens = [int(packed_list[i].n_ok) for i in live]
-    # the planner's floor only needs a width HINT (a coarser floor
-    # splits small keys into more groups — suboptimal packing, never
-    # incorrect); stage A's per-key widths keep keys of different mask
-    # cost in different groups
-    groups = reach_batch.plan_buckets(lens, max_slots, group=group,
-                                      key_W=sa.key_W, S=sa.S_pad)
-    pad_lanes = 0
-    if devices is not None and len(devices) > 1:
-        groups, pad_lanes = reach_batch.shard_groups_for_mesh(
-            groups, len(devices))
-    if len(groups) < 2:
-        return None         # nothing to hide — synchronous is simpler
-    try:
-        r = _dispatch_lockstep_stream(
-            sa, groups,
-            reach_batch.group_widths(groups, sa.key_W, sa.S_pad),
-            max_slots, len(live), diag, devices=devices,
-            pad_lanes=pad_lanes)
-    except Exception as e:                              # noqa: BLE001
-        # dispatch-side failure: recorded, then the synchronous path
-        # gets its chance (and takes the existing per-history
-        # fallbacks if it fails the same way)
-        obs.engine_fallback("stream-prep", type(e).__name__,
-                            groups=len(groups))
-        logging.getLogger("jepsen.reach").warning(
-            "streaming lockstep dispatch failed (%r); retrying the "
-            "synchronous path", e)
-        return None
-    if r is None:
-        return None
-    dead, key_W, key_R = r
-    elapsed = _time.monotonic() - t0
-    return _union_results_parts(engine, model, packed_list, live, dead,
-                                sa.memo_u, key_W, key_R,
-                                sa.drop_per_key(), elapsed, max_states,
-                                max_slots, max_dense)
+    return StagedMany(st, gdiags, _collect)
 
 
 def _check_many_lockstep(model: Model,
@@ -2714,113 +2569,32 @@ def _check_many_lockstep(model: Model,
                          ) -> Optional[List[Dict[str, Any]]]:
     """Bucketed-lockstep fast lane for :func:`check_many` — the
     production path for ragged ``independent`` batches: ONE union
-    memo + ONE native preprocessing call (as the keyed lane), then
-    length-bucketed lane packing (:func:`reach_batch.plan_buckets`) so
-    a long key never forces short keys through its padding, pipelined
-    group dispatch, and per-geometry compiled kernels cached across
-    groups. Aggregate throughput beats the keyed kernel because H keys
-    advance per lockstep step instead of one — the flat keyed stream
-    pays the per-issue latency wall once per RETURN, this lane once
-    per step. Returns the results list, or None to fall through to the
-    keyed kernel / vmapped XLA paths (no native lib, union explosion,
-    budget overflow, kernel failure)."""
-    from jepsen_tpu.checkers import preproc_native
-
-    if not (_use_pallas() and preproc_native.available()):
+    memo (stage A, shared with the keyed lane through ``u_box``), then
+    :func:`_stage_lockstep`'s length-bucketed lane packing, so a long
+    key never forces short keys through its padding, streamed group
+    dispatch, and per-geometry compiled kernels cached across groups,
+    collected at once. Aggregate throughput beats the keyed kernel
+    because H keys advance per lockstep step instead of one — the flat
+    keyed stream pays the per-issue latency wall once per RETURN, this
+    lane once per step. Returns the results list, or None to fall
+    through to the keyed kernel / vmapped XLA paths (no native lib,
+    union explosion, a producer decline or failure, a kernel
+    failure)."""
+    live = _lockstep_live(packed_list)
+    if live is None:
         return None
-    live = [i for i, p in enumerate(packed_list) if p.n and p.n_ok]
-    if len(live) < 2:
+    sa = _union_stage_a_shared(model, packed_list, live, max_states,
+                               u_box)
+    if sa is None:
         return None
-    if sum(packed_list[i].n_ok for i in live) < _PALLAS_MIN_RETURNS:
-        return None
-    if _stream_prep_enabled():
-        sa = _union_stage_a_shared(model, packed_list, live, max_states,
-                                   u_box)
-        if sa is None:
-            if u_box is not None:
-                u_box["u"] = None       # stage A failure implies no u
-            return None
-        out = _check_lockstep_stream(
+    try:
+        staged = _stage_lockstep(
             "reach-lockstep", model, packed_list, live, sa, max_states,
             max_slots, max_dense, group or _BATCH_GROUP, diag, t0)
-        if out is not None:
-            return out
-    u = _union_prep_shared(model, packed_list, live, max_states,
-                           max_slots, u_box)
-    if u is None:
-        return None
-    (_memo_u, _S_pad, P, _W, _M, ret_flat, ops_flat, key_W, _key_R,
-     offsets, _opid_cat, _crs_cat, _offs, _noop_op) = u
-    groups = _plan_union_groups(u, group or _BATCH_GROUP)
-    sa_box = (u_box or {}).get("sa")
-    try:
-        dead = _dispatch_lockstep_groups(
-            P, ret_flat, ops_flat, offsets, groups, key_W, len(live),
-            diag,
-            prep_base_s=sa_box.pack_s if sa_box is not None else 0.0)
+        return None if staged is None else staged.collect()
     except Exception as e:                              # noqa: BLE001
         _warn_pallas_failed(f"lockstep: {e!r}")
         return None
-    elapsed = _time.monotonic() - t0
-    return _union_results("reach-lockstep", model, packed_list, live,
-                          dead, u, elapsed, max_states, max_slots,
-                          max_dense)
-
-
-class StagedMany:
-    """A staged-but-uncollected :func:`check_many` lockstep batch: the
-    union prep ran, every dispatch group's walk is QUEUED on device
-    (host pack + puts + kernel launches paid), and nothing has been
-    fetched. Produced by :func:`stage_check_many`; a serve lane holds
-    K of these in flight so group k+1's stage overlaps group k's
-    device walk. ``collect()`` FIFO-fetches the few verdict words and
-    assembles results exactly as the synchronous lockstep lane would —
-    bit-identical verdicts by construction (same kernels, same
-    ``_union_results`` assembly). A collect-side device error
-    propagates to the caller's recovery ladder; the retained host
-    operands make the re-run safe."""
-
-    __slots__ = ("model", "packed_list", "live", "u", "st", "gdiags",
-                 "prep_s", "dispatch_s", "t0", "max_states",
-                 "max_slots", "max_dense", "dead")
-
-    def __init__(self, model, packed_list, live, u, st, gdiags,
-                 prep_s, dispatch_s, t0, max_states, max_slots,
-                 max_dense, dead):
-        self.model = model
-        self.packed_list = packed_list
-        self.live = live
-        self.u = u
-        self.st = st
-        self.gdiags = gdiags
-        self.prep_s = prep_s
-        self.dispatch_s = dispatch_s
-        self.t0 = t0
-        self.max_states = max_states
-        self.max_slots = max_slots
-        self.max_dense = max_dense
-        self.dead = dead
-
-    def ready(self) -> bool:
-        """True when every staged group's device results are resident
-        (collect would not block on the walk)."""
-        return all(dispatch_core.inflight_ready(fl)
-                   for _g, fl, _di in self.st.inflight)
-
-    def collect(self) -> List[Dict[str, Any]]:
-        """Fetch verdicts and assemble per-history results (the
-        accounting tail the synchronous scheduler emits per
-        dispatch)."""
-        self.st.collect(0)
-        _lockstep_accounting(self.gdiags, self.prep_s, 0.0, 0.0,
-                             self.dispatch_s, self.st.fetch_s,
-                             "pipeline", 0, None, self.st.mesh_info(0),
-                             self.st.fetch_degraded)
-        elapsed = _time.monotonic() - self.t0
-        return _union_results("reach-lockstep", self.model,
-                              self.packed_list, self.live, self.dead,
-                              self.u, elapsed, self.max_states,
-                              self.max_slots, self.max_dense)
 
 
 def stage_check_many(model: Model,
@@ -2829,16 +2603,15 @@ def stage_check_many(model: Model,
                      max_dense: int = 1 << 22,
                      group: int = 0
                      ) -> Optional["StagedMany | StagedVmapped"]:
-    """STAGE half of the pipelined :func:`check_many` lockstep route:
-    union prep + bucketed lane packing + every dispatch group's walk
-    queued on device, nothing fetched. Returns a :class:`StagedMany`
-    to collect later, or None when the batch is not stageable (gates
-    closed, too few live histories/returns, union prep declined) —
+    """STAGE half of the pipelined :func:`check_many` route: the same
+    gates, stage A and :func:`_stage_lockstep` as the blocking lockstep
+    lane, with the collect left to the caller. Returns a
+    :class:`StagedMany` to collect later, or None when the batch is
+    not stageable (gates closed, too few live histories/returns, union
+    explosion, a producer decline or failure, a dispatch failure) —
     the caller then runs the ordinary blocking chain, which redoes
-    nothing but the cheap gate checks. A failure AFTER some groups
-    dispatched drains them best-effort and declines, so a staged probe
-    can never leak in-flight device work."""
-    from jepsen_tpu.checkers import preproc_native, reach_batch
+    nothing but the cheap gate checks."""
+    from jepsen_tpu.checkers import preproc_native
 
     if not dispatch_core.pipeline_enabled():
         return None
@@ -2850,53 +2623,25 @@ def stage_check_many(model: Model,
                                    max_states=max_states,
                                    max_slots=max_slots,
                                    max_dense=max_dense)
-    live = [i for i, p in enumerate(packed_list) if p.n and p.n_ok]
-    if len(live) < 2:
-        return None
-    if sum(packed_list[i].n_ok for i in live) < _PALLAS_MIN_RETURNS:
+    live = _lockstep_live(packed_list)
+    if live is None:
         return None
     _ensure_persistent_caches()
     t0 = _time.monotonic()
-    u = _union_prep_shared(model, packed_list, live, max_states,
-                           max_slots, None)
-    if u is None:
+    sa = _union_stage_a(model, packed_list, live, max_states)
+    if sa is None:
         return None
-    (_memo_u, _S_pad, P, _W, _M, ret_flat, ops_flat, key_W, _key_R,
-     offsets, _opid_cat, _crs_cat, _offs, _noop_op) = u
-    groups = _plan_union_groups(u, group or _BATCH_GROUP)
-    widths = reach_batch.group_widths(groups, key_W, P.shape[1])
-    dead = np.full(len(live), -1, np.int64)
-    st = _LockstepDispatchState(None, dead)
-    gdiags: List[dict] = []
-    prep_s = dispatch_s = 0.0
     try:
-        for gi, g in enumerate(groups):
-            ta = _time.monotonic()
-            with obs.span("lockstep.prep", lanes=len(g)):
-                rets, ops, M = _group_streams(ret_flat, ops_flat,
-                                              offsets, g, widths[gi])
-                prep = reach_batch.prepare_returns_batch(
-                    P, rets, ops, M, key_W=[key_W[k] for k in g])
-            tb = _time.monotonic()
-            prep_s += tb - ta
-            gdiags.append(st.stage(gi, g, prep,
-                                   reach_batch.dispatch_prepared))
-            dispatch_s += _time.monotonic() - tb
+        return _stage_lockstep(
+            "reach-lockstep", model, packed_list, live, sa, max_states,
+            max_slots, max_dense, group or _BATCH_GROUP, None, t0)
     except Exception as e:                              # noqa: BLE001
         # jtlint: ok fallback — stage probe declines; the caller's
         # blocking chain re-runs the batch with its own fallback
         # ladder, so nothing is lost but the attempted launches
         obs.count("pipeline.stage_error")
         _warn_pallas_failed(f"stage: {e!r}")
-        try:
-            st.collect(0)
-        # jtlint: ok fallback — draining a poisoned probe is best-effort; the blocking re-run owns the verdicts
-        except Exception:                               # noqa: BLE001
-            pass
         return None
-    return StagedMany(model, packed_list, live, u, st, gdiags, prep_s,
-                      dispatch_s, t0, max_states, max_slots, max_dense,
-                      dead)
 
 
 def _stage_many_vmapped(model: Model,
@@ -2977,33 +2722,29 @@ def _check_many_mesh_lockstep(model: Model,
                               u_box: Optional[dict] = None
                               ) -> Optional[List[Dict[str, Any]]]:
     """Device-sharded lockstep lane for the MESH path of
-    :func:`check_many` (the ISSUE 4 tentpole): the same union stage A
-    and bucketed lane packing as the single-chip lockstep lane, with
-    the lockstep LANE axis sharded over ``devices`` — dispatch groups
-    are split into per-device lane blocks until every chip holds one
+    :func:`check_many`: the single-chip lane's stage A and
+    :func:`_stage_lockstep` with the lockstep LANE axis sharded over
+    ``devices`` — dispatch groups are split into per-device lane
+    blocks until every chip holds one
     (:func:`reach_batch.shard_groups_for_mesh`; pad lanes replicate a
     real lane, so verdicts stay exact) and placed round-robin in the
-    canonical mesh order, while the streaming prep thread multi-queues
-    groups so device k walks group g as device j walks group g+1.
-    Returns the results list, or None to fall through to the keyed
-    mesh-union lane (gates closed: ``JEPSEN_TPU_NO_MESH_LOCKSTEP=1``,
-    no Pallas, no native lib, union explosion/budget overflow, too few
-    returns, an unsplittable batch). A dispatch failure ON the mesh
-    (compile failure, padding overflow, device placement) records
-    exactly ONE ``mesh-lockstep`` fallback in the obs ledger and
-    re-runs the batch on the SINGLE-DEVICE lockstep lane — asking for
-    more chips must degrade to fewer chips on the SAME engine, never
-    silently to the keyed kernel."""
-    from jepsen_tpu.checkers import preproc_native, reach_batch
+    canonical mesh order, while the prep thread multi-queues groups so
+    device k walks group g as device j walks group g+1. Returns the
+    results list, or None to fall through to the keyed mesh-union lane
+    (gates closed: ``JEPSEN_TPU_NO_MESH_LOCKSTEP=1``, no Pallas, no
+    native lib, too few returns, union explosion, a producer decline
+    or failure). A dispatch failure ON the mesh (compile failure,
+    padding overflow, device placement) records exactly ONE
+    ``mesh-lockstep`` fallback in the obs ledger and re-runs the batch
+    on the SINGLE-DEVICE lockstep lane — asking for more chips must
+    degrade to fewer chips on the SAME engine, never silently to the
+    keyed kernel."""
+    from jepsen_tpu.checkers import reach_batch
 
     if not reach_batch.mesh_lockstep_enabled():
         return None
-    if not (_use_pallas() and preproc_native.available()):
-        return None
-    live = [i for i, p in enumerate(packed_list) if p.n and p.n_ok]
-    if len(live) < 2:
-        return None
-    if sum(packed_list[i].n_ok for i in live) < _PALLAS_MIN_RETURNS:
+    live = _lockstep_live(packed_list)
+    if live is None:
         return None
     from jepsen_tpu import parallel as par
 
@@ -3014,34 +2755,13 @@ def _check_many_mesh_lockstep(model: Model,
     sa = _union_stage_a_shared(model, packed_list, live, max_states,
                                u_box)
     if sa is None:
-        if u_box is not None:
-            u_box["u"] = None       # stage A failure implies no u
         return None
     try:
-        if _stream_prep_enabled():
-            out = _check_lockstep_stream(
-                "reach-lockstep-mesh", model, packed_list, live, sa,
-                max_states, max_slots, max_dense,
-                group or _BATCH_GROUP, diag, t0, devices=devs)
-            if out is not None:
-                return out
-        u = _union_prep_shared(model, packed_list, live, max_states,
-                               max_slots, u_box)
-        if u is None:
-            return None
-        (_memo_u, _S_pad, P, _W, _M, ret_flat, ops_flat, key_W, _key_R,
-         offsets, _opid_cat, _crs_cat, _offs, _noop_op) = u
-        groups = _plan_union_groups(u, group or _BATCH_GROUP)
-        groups, pad_lanes = reach_batch.shard_groups_for_mesh(
-            groups, len(devs))
-        if len(groups) < 2:
-            return None         # unsplittable: nothing to shard
-        sa_box = (u_box or {}).get("sa")
-        dead = _dispatch_lockstep_groups(
-            P, ret_flat, ops_flat, offsets, groups, key_W, len(live),
-            diag,
-            prep_base_s=sa_box.pack_s if sa_box is not None else 0.0,
-            devices=devs, pad_lanes=pad_lanes)
+        staged = _stage_lockstep(
+            "reach-lockstep-mesh", model, packed_list, live, sa,
+            max_states, max_slots, max_dense, group or _BATCH_GROUP,
+            diag, t0, devices=devs)
+        return None if staged is None else staged.collect()
     except Exception as e:                              # noqa: BLE001
         _warn_pallas_failed(f"mesh-lockstep: {e!r}")
         obs.engine_fallback("mesh-lockstep", type(e).__name__,
@@ -3050,10 +2770,6 @@ def _check_many_mesh_lockstep(model: Model,
                                     max_slots, max_dense, t0,
                                     group=group, diag=diag,
                                     u_box=u_box)
-    elapsed = _time.monotonic() - t0
-    return _union_results("reach-lockstep-mesh", model, packed_list,
-                          live, dead, u, elapsed, max_states,
-                          max_slots, max_dense)
 
 
 def _key_axis_shardings(devices: Sequence, n_keys: int):
@@ -3097,17 +2813,14 @@ def _check_many_mesh_native(model: Model,
     live = [i for i, p in enumerate(packed_list) if p.n and p.n_ok]
     if len(live) < 2:
         return None
-    # reuse the mesh-lockstep attempt's prep: a cached full u is
-    # directly valid (its gates are stricter), and a cached stage A
-    # skips re-paying the union BFS when only the Pallas gate failed
-    u = (u_box or {}).get("u")
-    if u is None:
-        sa = _union_stage_a_shared(model, packed_list, live, max_states,
-                                   u_box)
-        if sa is None:
-            return None
-        u = _union_prep(model, packed_list, live, max_states, max_slots,
-                        need_pallas=False, stage_a=sa)
+    # the mesh-lockstep attempt's cached stage A skips re-paying the
+    # union BFS
+    sa = _union_stage_a_shared(model, packed_list, live, max_states,
+                               u_box)
+    if sa is None:
+        return None
+    u = _union_prep(model, packed_list, live, max_states, max_slots,
+                    need_pallas=False, stage_a=sa)
     if u is None:
         return None
     (memo_u, S_pad, P, W, M, ret_flat, ops_flat, key_W, key_R,
@@ -3322,7 +3035,7 @@ def check_many(model: Model, packed_list: Sequence[h.PackedHistory], *,
         return [{"valid": "unknown", "cause": "aborted",
                  "engine": "reach-batch"} for _ in packed_list]
     if devices is None or len(devices) <= 1:
-        u_box: dict = {}        # one union prep shared by both lanes
+        u_box: dict = {}        # one stage A shared by both lanes
         out = _check_many_lockstep(model, packed_list,
                                    max_states=max_states,
                                    max_slots=max_slots,

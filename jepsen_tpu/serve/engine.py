@@ -20,7 +20,7 @@ verdicts are the standalone verdicts:
   single-history auto chain, abortable through the segmented walk);
 - a group of many goes through :func:`facade.auto_check_many_packed`,
   whose first route is the streaming lockstep batch scheduler
-  (``reach._dispatch_lockstep_stream``) — the admission coalescer
+  (``reach._stage_lockstep``) — the admission coalescer
   sized the group with the same ``plan_buckets`` packer, so the
   engine-side re-plan reproduces the group geometry.
 

@@ -12,7 +12,7 @@ serving hot path (and one engine-side hook), each a no-op until armed:
 - ``device``      — entry of the device route only (a device-path
                     outage: the circuit breaker's food).
 - ``prep``        — inside the streaming prep thread
-                    (``reach._dispatch_lockstep_stream``'s producer;
+                    (``reach._stage_lockstep``'s producer;
                     env-gated so the engine never imports this module
                     on a clean run).
 - ``persist``     — entry of the store persistence write.

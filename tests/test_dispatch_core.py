@@ -52,9 +52,9 @@ def test_dispatch_state_depth_and_round_robin():
 
 
 def test_reach_alias_is_the_shared_core():
-    """reach keeps ``_LockstepDispatchState`` as an alias — the sync
-    and stream schedulers run the SAME state machine as chunklock's
-    dispatches (no sixth choreography)."""
+    """reach keeps ``_LockstepDispatchState`` as an alias — the keyed
+    lockstep lane runs the SAME state machine as chunklock's
+    dispatches (no second choreography)."""
     assert reach._LockstepDispatchState is dispatch_core.DispatchState
     assert reach._LOCKSTEP_PIPE_DEPTH == dispatch_core.PIPE_DEPTH
 

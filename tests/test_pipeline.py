@@ -71,10 +71,10 @@ def _drive(k, n, host_s, dev_s, monkeypatch):
     for gi in range(n):
         time.sleep(host_s)                       # the host pack stage
         prep = types.SimpleNamespace(device=None)
-        st.stage(gi, [gi], prep,
-                 lambda _p, gi=gi: _FakeFl(100 + gi, dev_s))
-        st.collect(st.depth)
-    st.collect(0)
+        di, _sp = st.place(gi, [gi], prep)
+        st.admit([gi], _FakeFl(100 + gi, dev_s), di)
+        st.drain(st.depth)
+    st.drain(0)
     return time.monotonic() - t0, dead, st
 
 
@@ -139,7 +139,8 @@ def test_collect_ready_stops_at_first_walking_group(monkeypatch):
         fl.word_out = (p,)
         probes.append(p)
         prep = types.SimpleNamespace(device=None)
-        st.stage(gi, [gi], prep, lambda _p, fl=fl: fl)
+        di, _sp = st.place(gi, [gi], prep)
+        st.admit([gi], fl, di)
         fls.append(fl)
     st.collect_ready(0)
     assert dead.tolist() == [-1, -1]            # nothing resident yet

@@ -27,13 +27,11 @@ needs_mesh = pytest.mark.skipif(
 def _force_mesh(monkeypatch):
     """Open the lockstep gates on CPU with the batch kernel in
     interpret mode and a small planner floor (several groups per
-    batch), and make sure neither the streaming nor the mesh lane is
-    env-disabled."""
+    batch), and make sure the mesh lane is not env-disabled."""
     monkeypatch.setattr(reach, "_use_pallas", lambda: True)
     monkeypatch.setattr(reach, "_PALLAS_MIN_RETURNS", 0)
     monkeypatch.setattr(reach_batch, "_INTERPRET_DEFAULT", True)
     monkeypatch.setattr(reach_batch, "_adaptive_block", lambda H, W: 64)
-    monkeypatch.delenv("JEPSEN_TPU_NO_STREAM_PREP", raising=False)
     monkeypatch.delenv("JEPSEN_TPU_NO_MESH_LOCKSTEP", raising=False)
 
 
@@ -123,7 +121,7 @@ def test_mesh_check_batch_crashes_and_diag_threading(monkeypatch):
     assert all(r["engine"] == "reach-lockstep-mesh" for r in res)
     # the ISSUE-named small fix: diagnostics survive the mesh path
     assert diag.get("mesh", {}).get("n_devices") == 4
-    assert diag.get("prep", {}).get("mode") in ("stream", "sync")
+    assert diag.get("prep", {}).get("mode") == "stream"
     for i, (a, r) in enumerate(zip(res, refs)):
         assert a["valid"] == r["valid"], f"key {i}"
         if a["valid"] is False:

@@ -783,22 +783,19 @@ def test_loadgen_chaos_tolerant_classifies_restart_errors():
 
 def test_prep_thread_fault_falls_back_exactly_once(monkeypatch):
     """The chaos harness's 'prep-thread death' fault, end to end
-    through the real streaming scheduler: the producer dies on the
-    injected fault, the batch re-runs synchronously with bit-identical
-    verdicts, and exactly ONE stream-prep fallback is ledgered."""
+    through the real lockstep lane: the producer dies on the injected
+    fault, exactly ONE stream-prep fallback is ledgered, and the batch
+    goes to the next lane, whose verdicts are the sequential ones."""
     from jepsen_tpu.checkers import preproc_native, reach, reach_batch
     if not preproc_native.available():
         pytest.skip("native preprocessing library unavailable")
     # open the lockstep gates on CPU + split the mix into several
     # groups, exactly like tests/test_stream_prep.py's _force_stream
-    # (a single-group plan declines streaming before the producer
-    # ever runs)
     monkeypatch.setattr(reach, "_use_pallas", lambda: True)
     monkeypatch.setattr(reach, "_PALLAS_MIN_RETURNS", 0)
     monkeypatch.setattr(reach_batch, "_INTERPRET_DEFAULT", True)
     monkeypatch.setattr(reach_batch, "_adaptive_block",
                         lambda H, W: 64)
-    monkeypatch.delenv("JEPSEN_TPU_NO_STREAM_PREP", raising=False)
     monkeypatch.setenv("JEPSEN_TPU_SERVE_FAULTS", "prep@1")
     faults.reset()
     faults.arm("prep", at=1)

@@ -494,7 +494,6 @@ def _force_lockstep(monkeypatch):
     monkeypatch.setattr(reach, "_use_pallas", lambda: True)
     monkeypatch.setattr(reach, "_PALLAS_MIN_RETURNS", 0)
     monkeypatch.setattr(reach_batch, "_INTERPRET_DEFAULT", True)
-    monkeypatch.delenv("JEPSEN_TPU_NO_STREAM_PREP", raising=False)
 
 
 @needs_native

@@ -163,14 +163,16 @@ def test_stage_a_widths_match_the_native_build():
 
 
 @needs_native
-@pytest.mark.parametrize("stream", [True, False])
-def test_wide_groups_match_per_key(monkeypatch, stream):
-    """A mixed batch with crashed ops through ``check_many``'s lockstep
-    lane, the one-array kernel's budget cut so that keys of W >= 4
-    walk the wide-M kernel (in 4-row tiles, so both its within-tile
-    and across-tile steps run), under both schedulers: verdicts, dead
-    events and failing ops equal the per-key path's, and every key is
-    answered by ``reach-lockstep``."""
+@pytest.mark.parametrize("entry", ["check_many", "check_batch",
+                                   "stage_check_many"])
+def test_wide_groups_match_per_key(monkeypatch, entry):
+    """A mixed batch with crashed ops through the keyed lockstep lane,
+    from each of its entry points (the staged one collected at once),
+    the one-array kernel's budget cut so that keys of W >= 4 walk the
+    wide-M kernel (in 4-row tiles, so both its within-tile and
+    across-tile steps run): verdicts, dead events and failing ops
+    equal the per-key path's, and every key is answered by
+    ``reach-lockstep``."""
     packs = _crashy_packs()
     assert 1 <= max(int(p.crashed.sum()) for p in packs) <= 3
     refs = [reach.check_packed(models.cas_register(), p) for p in packs]
@@ -183,14 +185,20 @@ def test_wide_groups_match_per_key(monkeypatch, stream):
     monkeypatch.setattr(reach, "_use_pallas", lambda: True)
     monkeypatch.setattr(reach, "_PALLAS_MIN_RETURNS", 0)
     monkeypatch.setattr(reach_batch, "_INTERPRET_DEFAULT", True)
-    if not stream:
-        monkeypatch.setenv("JEPSEN_TPU_NO_STREAM_PREP", "1")
+    model = models.cas_register()
     diag = {}
-    res = reach.check_many(models.cas_register(), packs, diag=diag)
+    if entry == "stage_check_many":
+        staged = reach.stage_check_many(model, packs)
+        assert isinstance(staged, reach.StagedMany)
+        res = staged.collect()
+        gdiags = staged.gdiags
+    else:
+        res = getattr(reach, entry)(model, packs, diag=diag)
+        gdiags = diag["groups"]
     assert all(r["engine"] == "reach-lockstep" for r in res)
-    widths = [g["W"] for g in diag["groups"]]
+    widths = [g["W"] for g in gdiags]
     assert min(widths) < 4 <= max(widths)
-    for g in diag["groups"]:
+    for g in gdiags:
         assert 0 < g["own_rows"] <= g["mask_rows"] <= g["rows"]
         assert g["mask_rows"] == g["M"] * g["real_returns"]
     for i, (a, b) in enumerate(zip(res, refs)):

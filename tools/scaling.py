@@ -117,7 +117,7 @@ def _worker(n_dev: int, keys: int, key_ops: int, chunk_ops: int,
     reach._use_pallas = lambda: True
     reach._PALLAS_MIN_RETURNS = 0
     reach_batch._INTERPRET_DEFAULT = True
-    for k in ("JEPSEN_TPU_NO_MESH_LOCKSTEP", "JEPSEN_TPU_NO_STREAM_PREP",
+    for k in ("JEPSEN_TPU_NO_MESH_LOCKSTEP",
               "JEPSEN_TPU_NO_PACKED_XFER", "JEPSEN_TPU_NO_LAZY_FETCH",
               "JEPSEN_TPU_NO_DONATE"):
         os.environ.pop(k, None)   # the rung measures the mesh lane on
